@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True unless a real TPU backend is present —
-frameworks flip to compiled kernels transparently on hardware, while CPU
-CI exercises the identical kernel bodies through the Pallas interpreter.
+Kernels are compiled on the TPU and run through the Pallas interpreter on
+the CPU, so CPU tests exercise the identical kernel bodies.  Any other
+backend is an error: nothing falls back to the interpreter silently.
 """
 from __future__ import annotations
 
@@ -18,11 +18,13 @@ from . import flash_attention as _fa
 from . import ssm_scan as _ss
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
         return False
+    raise RuntimeError(f"no Pallas kernels for the {backend!r} backend")
 
 
 @functools.partial(jax.jit, static_argnames=("banks", "block", "out_dtype"))
@@ -31,7 +33,7 @@ def matmul(a: jax.Array, b: jax.Array,
            block: Optional[Tuple[int, int, int]] = None,
            out_dtype=None) -> jax.Array:
     return _bm.banked_matmul(a, b, banks=banks, block=block,
-                             out_dtype=out_dtype, interpret=not _on_tpu())
+                             out_dtype=out_dtype, interpret=_interpret())
 
 
 @functools.partial(jax.jit,
@@ -40,16 +42,16 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
               block_q: int = 128, block_k: int = 128) -> jax.Array:
     return _fa.flash_attention(q, k, v, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k,
-                               interpret=not _on_tpu())
+                               interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "diag_mode"))
 def decay_scan(q, k, v, w, u=None, chunk: int = 32,
                diag_mode: str = "inclusive") -> jax.Array:
     return _ss.ssm_scan(q, k, v, w, u=u, chunk=chunk, diag_mode=diag_mode,
-                        interpret=not _on_tpu())
+                        interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("banks",))
 def conv2d(x, w, banks: Tuple[int, int] = (1, 1)) -> jax.Array:
-    return _bc.banked_conv2d(x, w, banks=banks, interpret=not _on_tpu())
+    return _bc.banked_conv2d(x, w, banks=banks, interpret=_interpret())

@@ -29,22 +29,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _window_sums(w: jax.Array, chunk: int) -> jax.Array:
-    """Pairwise decay sums ``out[t, s, d] = sum_{s < i <= t} w[i, d]``.
+def _sums(mask: jax.Array, w: jax.Array) -> jax.Array:
+    """``out[r, d] = sum_i mask[r, i] w[i, d]`` as a 0/1 matmul at full f32
+    precision (the TPU kernel language has no cumsum).  Every entry is a
+    direct sum over its own window, never a difference of two running sums:
+    subtracting two long accumulations ``W_t - W_s`` cancels catastrophically
+    once |W| grows with the chunk length, which is exactly what made
+    large-chunk runs drift from small-chunk runs.  Here the rounding error of
+    each entry is proportional to the *window* magnitude — large windows have
+    vanishing ``exp`` anyway, so the error lands where it cannot matter."""
+    return jnp.dot(mask.astype(jnp.float32), w,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
-    Computed directly as per-window running sums (a fresh cumsum restarted
-    after every ``s``) rather than as the cumsum difference ``W_t - W_s``:
-    subtracting two long accumulations cancels catastrophically once |W|
-    grows with the chunk length, which is exactly what made large-chunk
-    runs drift from small-chunk runs.  Here the rounding error of each
-    entry is proportional to the *window* magnitude — large windows have
-    vanishing ``exp`` anyway, so the error lands where it cannot matter.
-    """
-    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)  # (s, i)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    gated = jnp.where((i_idx > s_idx)[:, :, None], w[None, :, :], 0.0)
-    win = jnp.cumsum(gated, axis=1)       # win[s, t, d] = sum_{s < i <= t}
-    return jnp.transpose(win, (1, 0, 2))  # (t, s, d)
+
+def _window_sums(w: jax.Array, chunk: int, inclusive: bool) -> jax.Array:
+    """Pairwise decay sums ``out[t, s, d] = sum_{s < i <= t} w[i, d]``
+    (``inclusive``) or ``sum_{s < i < t}``, one matmul over the (t, s) rows."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (chunk * chunk, chunk), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (chunk * chunk, chunk), 1)
+    t, s = r // chunk, r % chunk
+    mask = (s < i) & ((i <= t) if inclusive else (i < t))
+    return _sums(mask, w).reshape(chunk, chunk, w.shape[-1])
 
 
 def _scan_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, h_ref, *,
@@ -59,52 +65,37 @@ def _scan_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, h_ref, *,
     k = k_ref[0].astype(jnp.float32)      # (C, dk)
     v = v_ref[0].astype(jnp.float32)      # (C, dv)
     w = w_ref[0].astype(jnp.float32)      # (C, dk), log-decays (<= 0)
-
-    W = jnp.cumsum(w, axis=0)             # (C, dk) inclusive cumulative decay
     h0 = h_ref[...]                       # (dk, dv) state before this chunk
-    win = _window_sums(w, chunk)          # (C, C, dk) exact window decays
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
 
-    if diag_mode == "inclusive":
-        # o_t = q_t . h_t ; h_t includes token t
-        qW = q * jnp.exp(W)               # decay from chunk start to t
-        o_inter = jnp.dot(qW, h0, preferred_element_type=jnp.float32)
-        # intra: sum_{s<=t} exp(sum_{s<i<=t} w_i) (q_t.k_s) v_s
-        # (exponent masked BEFORE exp: upper triangle overflows otherwise)
-        diff = jnp.where((s_idx <= t_idx)[:, :, None], win, -1e30)
-        rel = jnp.exp(diff)                               # (C, C, dk)
-        scores = jnp.einsum("td,tsd,sd->ts", q, rel, k)
-        o = o_inter + jnp.dot(scores, v, preferred_element_type=jnp.float32)
-    else:  # bonus (RWKV6): o_t reads h_{t-1}, diag via u
-        # exclusive cumulative decay (chunk start .. t-1) as a shift of the
-        # inclusive one — W - w would reintroduce the cancellation
-        Wprev = jnp.concatenate([jnp.zeros((1,) + W.shape[1:], W.dtype),
-                                 W[:-1]], axis=0)
-        qW = q * jnp.exp(Wprev)
-        o_inter = jnp.dot(qW, h0, preferred_element_type=jnp.float32)
-        # exponent sum_{s<i<=t-1} w_i = win[t-1, s]: shift win along t
-        shifted = jnp.concatenate(
-            [jnp.zeros((1, chunk, win.shape[2]), win.dtype), win[:-1]],
-            axis=0)
-        diff = jnp.where((s_idx < t_idx)[:, :, None], shifted, -1e30)
-        rel = jnp.exp(diff)                               # s <= t-1
-        scores = jnp.einsum("td,tsd,sd->ts", q, rel, k)
-        o = o_inter + jnp.dot(scores, v, preferred_element_type=jnp.float32)
-        u = u_ref[...].astype(jnp.float32)                # (1, dk)
+    # inclusive (Mamba2/SSD): o_t = q_t . h_t, token t included via the
+    # decay path; bonus (RWKV6): o_t reads h_{t-1}, the diagonal comes via u
+    inclusive = diag_mode == "inclusive"
+    live = (s_idx <= t_idx) if inclusive else (s_idx < t_idx)
+    W = _sums(live, w)                    # decay chunk start .. t (or t-1)
+    o_inter = jnp.dot(q * jnp.exp(W), h0, preferred_element_type=jnp.float32)
+    # intra: sum_{s live} exp(sum_{s<i<=t (or <t)} w_i) (q_t . k_s) v_s; the
+    # window of a dead pair is empty (exp(0) = 1), so mask the scores
+    rel = jnp.exp(_window_sums(w, chunk, inclusive))          # (C, C, dk)
+    scores = jnp.sum(q[:, None, :] * rel * k[None, :, :], axis=-1)
+    scores = jnp.where(live, scores, 0.0)
+    o = o_inter + jnp.dot(scores, v, preferred_element_type=jnp.float32)
+    if not inclusive:
+        u = u_ref[0].astype(jnp.float32)                  # (1, dk)
         bonus = jnp.sum(q * u * k, axis=1, keepdims=True) # (C, 1)
         o = o + bonus * v
 
     o_ref[0] = o.astype(o_ref.dtype)
 
     # state update: h' = exp(W_last) h0 + sum_s exp(sum_{s<i} w_i) k_s v_s.
-    # The per-position suffix decays are the last row of the window table
-    # (again direct sums, never W_last - W_s), and the full-chunk decay is a
-    # plain reduction — both keep the f32 carry consistent across chunkings.
-    w_total = jnp.sum(w, axis=0)                           # (dk,)
-    k_dec = k * jnp.exp(win[-1])                           # (C, dk)
-    h_ref[...] = (jnp.exp(w_total)[:, None] * h0
-                  + jnp.dot(k_dec.T, v, preferred_element_type=jnp.float32))
+    # The per-position suffix decays are direct sums again (never
+    # W_last - W_s), and the full-chunk decay is a plain reduction — both
+    # keep the f32 carry consistent across chunkings.
+    k_dec = k * jnp.exp(_sums(t_idx < s_idx, w))           # (C, dk)
+    decay = jnp.exp(jnp.sum(w.T, axis=1, keepdims=True))   # (dk, 1)
+    h_ref[...] = decay * h0 + jnp.dot(k_dec.T, v,
+                                      preferred_element_type=jnp.float32)
 
 
 def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
@@ -127,7 +118,9 @@ def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     kf = k.reshape(b * h, s, dk)
     vf = v.reshape(b * h, s, dv)
     wf = w.reshape(b * h, s, dk)
-    uf = jnp.tile(u, (b, 1)).reshape(b * h, dk)
+    # (B*H, 1, dk) so the (1, 1, dk) block equals the array in its last two
+    # dims, as the TPU tiling rule asks of a block narrower than (8, 128)
+    uf = jnp.tile(u, (b, 1)).reshape(b * h, 1, dk)
 
     kernel = functools.partial(_scan_kernel, chunk=chunk, diag_mode=diag_mode)
     out = pl.pallas_call(
@@ -138,7 +131,7 @@ def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, chunk, dk), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, dv), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, dk), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, dk), lambda bh, c: (bh, 0)),
+            pl.BlockSpec((1, 1, dk), lambda bh, c: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dv), lambda bh, c: (bh, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),

@@ -1,6 +1,11 @@
 """Production serving launcher: continuous batched decode loop.
 
+    # published config (the chip: see chip_smoke.py at the repo root)
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+        --slots 4 --requests 12 --gen 16
+
+    # reduced config (CPU-scale), with metrics and spans
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
         --slots 4 --requests 12 --gen 16 \
         --metrics-out /tmp/serve.prom --spans-out /tmp/serve_spans.jsonl
 
@@ -51,6 +56,7 @@ import numpy as np
 
 from repro.launch import faults as FLT
 from repro.launch import resilience as RES
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import decode, get_config
 from repro.models import params as MP
 from repro.obs import MetricsRegistry, SpanTracer, spans as SP, traffic
@@ -764,9 +770,26 @@ def replay(eng: Engine, arrivals: Sequence[Tuple[int, Request]]) -> None:
         drv.tick()
 
 
+def synth_arrivals(cfg, seed: int, requests: int, arrival_mean: float,
+                   prompt_len: int, gen: int) -> List[Tuple[int, Request]]:
+    """Seeded ``(arrival_step, Request)`` schedule with random prompts."""
+    rng = np.random.default_rng(seed)
+    trace = traffic.synth_trace(seed, requests, arrival_mean, [prompt_len],
+                                [gen])
+    return [(t.arrival_step,
+             Request(t.rid,
+                     rng.integers(1, cfg.vocab_size,
+                                  size=t.prompt_len).astype(np.int32),
+                     t.gen_len))
+            for t in trace]
+
+
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced config (CPU-scale)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -806,8 +829,9 @@ def main():
                          "or deadlines configured")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
-    rng = np.random.default_rng(args.seed)
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     params = MP.init_params(cfg, seed=args.seed)
     per_req = args.prompt_len + args.gen
     if args.arrival_mean > 0:
@@ -830,14 +854,8 @@ def main():
         # by tripping the max_len guard
         max_len = max_len * 2 + 64
 
-    trace = traffic.synth_trace(args.seed, args.requests, args.arrival_mean,
-                                [args.prompt_len], [args.gen])
-    arrivals = [(t.arrival_step,
-                 Request(t.rid,
-                         rng.integers(1, cfg.vocab_size,
-                                      size=t.prompt_len).astype(np.int32),
-                         t.gen_len))
-                for t in trace]
+    arrivals = synth_arrivals(cfg, args.seed, args.requests,
+                              args.arrival_mean, args.prompt_len, args.gen)
 
     metrics = MetricsRegistry() if args.metrics_out else None
     spans_tr = SpanTracer() if args.spans_out else None

@@ -12,15 +12,18 @@ JAX distributed bootstrap; the mesh/sharding config is identical to what
 the dry-run validates.
 """
 import argparse
+import os
 import tempfile
 
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import get_config
 from repro.optim import adamw
 from repro.runtime.trainer import Trainer, TrainerConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -38,13 +41,14 @@ def main():
 
     if args.dry_run:
         # delegate to the dry-run module (must own process startup for the
-        # 512-device host platform flag)
+        # 512-device host platform flag); it compiles for fake CPU devices
+        # only, so it is kept off any accelerator this host may hold
         import subprocess
         import sys
         raise SystemExit(subprocess.call(
             [sys.executable, "-m", "repro.launch.dryrun",
              "--arch", args.arch, "--shape", "train_4k", "--both",
-             "--force"]))
+             "--force"], env={**os.environ, "JAX_PLATFORMS": "cpu"}))
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -230,8 +230,10 @@ def _init_leaf(rng: np.random.Generator, lf: Leaf, dtype, d_model: int):
                            dtype)
     if kind == "decay_base":
         return jnp.asarray(rng.uniform(-7.0, -5.0, shape), dtype)
-    scale = 0.02 if kind == "embed" else 1.0 / math.sqrt(max(shape[0] if
-                                                             shape else 1, 1))
+    # fan-in is the contraction dim, second from last: a leading dim of a
+    # stacked leaf counts layers (or experts), not inputs
+    fan_in = shape[-2] if len(shape) >= 2 else 1
+    scale = 0.02 if kind == "embed" else 1.0 / math.sqrt(fan_in)
     arr = rng.normal(0.0, scale, shape).astype(np.float32)
     return jnp.asarray(arr, dtype)
 

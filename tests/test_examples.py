@@ -123,7 +123,7 @@ class TestExamples:
     def test_serve_launcher(self, tmp_path):
         metrics = tmp_path / "serve.json"
         spans = tmp_path / "serve.jsonl"
-        out = _run(["-m", "repro.launch.serve", "--slots", "2",
+        out = _run(["-m", "repro.launch.serve", "--reduced", "--slots", "2",
                     "--requests", "3", "--gen", "4", "--prompt-len", "4",
                     "--metrics-out", str(metrics),
                     "--spans-out", str(spans), "--stable"])

@@ -173,3 +173,16 @@ class TestBankedConv2d:
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(expect, np.float32),
                                    **_TOL[dtype])
+
+
+class TestBackend:
+    @pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                                   ("tpu", False)])
+    def test_interpreter_only_on_cpu(self, monkeypatch, backend, interpret):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops._interpret() is interpret
+
+    def test_other_backend_raises(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()

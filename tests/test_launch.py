@@ -1,5 +1,7 @@
 """Launch layer: shapes, sharding rules, cell skip logic, model flops
 (host-mesh scale — the 512-device path is exercised by dryrun itself)."""
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import shapes as SH
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.dryrun import model_flops
 from repro.launch.shapes import SHAPES, cell_supported
 from repro.models import all_names, get_config
@@ -56,6 +59,16 @@ class TestParamSpecs:
         spec = sanitize_spec(P("model", "data"), (51866, 1280), mesh)
         assert spec == P("model", "data")  # 1-device axes always divide
 
+    def test_init_scales_by_fan_in(self):
+        # stacked leaves lead with the group axis; the scale must not see it
+        cfg = get_config("qwen2-0.5b").reduced()
+        lyr = MP.init_params(cfg, seed=0)["blocks"]["lyr"]
+        for w, fan_in in ((lyr["attn"]["wq"], cfg.d_model),
+                          (lyr["mlp"]["wo"], cfg.d_ff)):
+            assert w.shape[0] == cfg.num_groups
+            np.testing.assert_allclose(float(np.std(np.asarray(w))),
+                                       fan_in ** -0.5, rtol=0.1)
+
     def test_param_count_magnitudes(self):
         # sanity vs published sizes (within 25%)
         expect = {"qwen2-0.5b": 0.49e9, "qwen2-7b": 7.6e9,
@@ -103,3 +116,24 @@ class TestInputSpecs:
             specs = SH.input_specs(cfg, shape, mesh, st)
             leaves = jax.tree.leaves(specs)
             assert leaves and all(hasattr(l, "shape") for l in leaves)
+
+
+class TestCompileCache:
+    def test_env_dir_is_left_to_jax(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        before = jax.config.jax_compilation_cache_dir
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_fixed_dir_inside_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            got = use_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+            assert use_compile_cache() == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(got) == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
