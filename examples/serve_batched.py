@@ -346,7 +346,8 @@ def main():
                                engine_steps=steps_run)
         assert not problems, problems
         with open(args.spans_out, "w") as f:
-            f.write(SP.to_jsonl(spans_tr.events, stable=args.stable))
+            f.write(SP.to_jsonl(spans_tr.events, stable=args.stable,
+                                epoch_ns=spans_tr.epoch_ns))
         print(f"{len(spans_tr.events)} span events -> {args.spans_out}"
               f"{' (stable)' if args.stable else ''}")
     if layers is not None:

@@ -27,6 +27,9 @@ enqueue -> admit -> prefill -> first_token -> complete phase chain; one
 event per engine step carries slot occupancy, queue depth, and tokens
 emitted.  Under a fixed ``--seed`` the span stream is byte-identical across
 runs in the exporter's ``--stable`` mode (wall-clock fields normalized).
+The engine's profiler spans (``serve.step`` and its phases, ``serve.admit``,
+``serve.init_cache``; see ``Engine.step``) are unconditional: with no trace
+active each is one object and two calls that record nothing.
 
 Resilience (``repro.launch.resilience`` + ``repro.launch.faults``): the
 engine optionally takes a :class:`~repro.launch.faults.FaultPlan` (seeded,
@@ -46,13 +49,13 @@ time, so the whole failure/recovery schedule is deterministic under a seed
 and the chaos span streams stay byte-identical in ``--stable`` mode.
 """
 import argparse
-import functools
 import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.launch import faults as FLT
 from repro.launch import resilience as RES
@@ -103,8 +106,6 @@ def serve_metrics(reg: MetricsRegistry, cfg, slots: int, cache) -> dict:
               "bytes held by the decode cache").set(st["cache_bytes"])
     reg.gauge("serve_cache_max_len",
               "cache positions available").set(st["cache_max_len"])
-    reg.gauge("serve_approx_flops_per_token",
-              "2 x active params").set(st["approx_flops_per_token"])
     m = {
         "enq": reg.counter("serve_requests_enqueued_total",
                            "requests submitted to the queue"),
@@ -149,13 +150,22 @@ def serve_metrics(reg: MetricsRegistry, cfg, slots: int, cache) -> dict:
     return m
 
 
-@functools.lru_cache(maxsize=None)
-def _guarded_argmax():
-    """Fused sample + finite-screen: one dispatch returns the argmax row
-    per slot and whether every logit in that row is finite."""
-    return jax.jit(lambda last: (
-        jnp.argmax(last, axis=-1).astype(jnp.int32),
-        jnp.all(jnp.isfinite(last), axis=-1)))
+# The engine's per-step programs besides the jitted step, each named by its
+# function (``jit_sample_argmax``, ``jit_sample_guarded_argmax``) so that a
+# trace's module line says which one ran.
+@jax.jit
+def sample_argmax(logits):
+    """Greedy token of each slot from its last logits row."""
+    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+
+
+@jax.jit
+def sample_guarded_argmax(logits):
+    """Greedy token of each slot, and whether every logit of that row is
+    finite (the finite guard), in one program."""
+    last = logits[:, -1]
+    return (jnp.argmax(last, axis=-1).astype(jnp.int32),
+            jnp.all(jnp.isfinite(last), axis=-1))
 
 
 class Engine:
@@ -177,13 +187,14 @@ class Engine:
         # tests) whose cache travels in per-group list form; the fused
         # engine pays nothing for the feature existing
         self.layers = layers
-        if layers is not None:
-            self._prof = decode.make_profiled_serve_step(cfg)
-            self.cache = decode.ProfiledServeStep.init_cache(
-                cfg, params, slots, max_len)
-        else:
-            self._prof = None
-            self.cache = decode.init_cache(cfg, params, slots, max_len)
+        with TraceAnnotation("serve.init_cache"):
+            if layers is not None:
+                self._prof = decode.make_profiled_serve_step(cfg)
+                self.cache = decode.ProfiledServeStep.init_cache(
+                    cfg, params, slots, max_len)
+            else:
+                self._prof = None
+                self.cache = decode.init_cache(cfg, params, slots, max_len)
         self._step = decode.make_serve_step(cfg)
         self.steps = 0
         self.queue: List[Request] = []
@@ -364,6 +375,10 @@ class Engine:
 
     def admit(self, queue: Optional[List[Request]] = None) -> None:
         """Fill free slots from ``queue`` (default: the engine's own)."""
+        with TraceAnnotation("serve.admit"):
+            self._admit(queue)
+
+    def _admit(self, queue: Optional[List[Request]]) -> None:
         q = self.queue if queue is None else queue
         if queue is None:
             self._release_delayed()
@@ -462,6 +477,18 @@ class Engine:
         self.steps += 1
 
     def step(self) -> None:
+        """One engine step, as the ``serve.step`` span of a profiler trace
+        (``step_num`` = the engine's step count) with its phases as child
+        spans: ``serve.feed`` (build and upload the tokens and position),
+        ``serve.dispatch`` (the jitted step call), ``serve.sample`` (argmax
+        and its download), ``serve.sync`` (wait for the cache) and
+        ``serve.bookkeep`` (tokens, completions, spans, metrics,
+        deadlines).  With no profiler attached each span finds no active
+        trace and records nothing."""
+        with StepTraceAnnotation("serve.step", step_num=self.steps):
+            self._run_step()
+
+    def _run_step(self) -> None:
         pending = self.faults.at(self.steps) if self.faults is not None \
             else ()
         observing = self.spans is not None or self._m is not None
@@ -492,144 +519,153 @@ class Engine:
                 self._m["fdet"].inc(n_exc)
             self._abort_step(observing, t0, spike_ticks, spike_us)
             return
-        toks = np.zeros((len(self.slots), 1), np.int32)
-        prefill_started: List[int] = []
-        fed_slots: List[int] = []
-        prefill_fed = 0
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            if r.fed < len(r.prompt):
-                if r.fed == 0:
-                    prefill_started.append(r.rid)
-                toks[i, 0] = r.prompt[r.fed]
-                r.fed += 1
-                fed_slots.append(i)
-                prefill_fed += 1
-            elif r.out:
-                toks[i, 0] = r.out[-1]
-        if self.spans is not None:
-            for rid in prefill_started:
-                self.spans.emit(SP.REQ_PREFILL, prov=SP.req_prov(rid),
-                                step=self.steps, rid=rid)
-        occupied = self.inflight
+        with TraceAnnotation("serve.feed"):
+            toks = np.zeros((len(self.slots), 1), np.int32)
+            prefill_started: List[int] = []
+            fed_slots: List[int] = []
+            prefill_fed = 0
+            for i, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                if r.fed < len(r.prompt):
+                    if r.fed == 0:
+                        prefill_started.append(r.rid)
+                    toks[i, 0] = r.prompt[r.fed]
+                    r.fed += 1
+                    fed_slots.append(i)
+                    prefill_fed += 1
+                elif r.out:
+                    toks[i, 0] = r.out[-1]
+            if self.spans is not None:
+                for rid in prefill_started:
+                    self.spans.emit(SP.REQ_PREFILL, prov=SP.req_prov(rid),
+                                    step=self.steps, rid=rid)
+            occupied = self.inflight
+            # host arrays go up as transfers, with no program of their own
+            toks_d, pos_d = jax.device_put(
+                (toks, np.array(self.pos, np.int32)))
         seg_walls: Optional[List[float]] = None
-        try:
-            if self._prof is not None:
-                logits, self.cache, seg_walls = self._prof(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.pos, jnp.int32))
+        with TraceAnnotation("serve.dispatch"):
+            try:
+                if self._prof is not None:
+                    logits, self.cache, seg_walls = self._prof(
+                        self.params, self.cache, toks_d, pos_d)
+                else:
+                    logits, self.cache = self._step(
+                        self.params, self.cache, toks_d, pos_d)
+            except Exception:
+                if self.res is None:
+                    raise
+                # genuine runtime failure: roll back this step's prompt
+                # feeds (no cache was written) and degrade instead of
+                # crashing
+                for i in fed_slots:
+                    r = self.slots[i]
+                    if r is not None:
+                        r.fed -= 1
+                self.faults_detected += 1
+                if self._m is not None:
+                    self._m["fdet"].inc()
+                self._abort_step(observing, t0, spike_ticks, spike_us)
+                return
+        with TraceAnnotation("serve.sample"):
+            for f in pending:
+                if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):
+                    injected += 1
+                    bad_val = jnp.nan if f.kind == FLT.NAN_LOGITS \
+                        else jnp.inf
+                    logits = logits.at[f.slot, -1].set(bad_val)
+            if self.res is not None and self.res.finite_guard:
+                nxt_d, fin_d = sample_guarded_argmax(logits)
+                nxt = np.asarray(nxt_d, np.int32)
+                finite = np.asarray(fin_d)
             else:
-                logits, self.cache = self._step(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.pos, jnp.int32))
-        except Exception:
-            if self.res is None:
-                raise
-            # genuine runtime failure: roll back this step's prompt feeds
-            # (no cache was written) and degrade instead of crashing
-            for i in fed_slots:
-                r = self.slots[i]
-                if r is not None:
-                    r.fed -= 1
-            self.faults_detected += 1
+                nxt = np.asarray(sample_argmax(logits), np.int32)
+                finite = None
+            for f in pending:
+                if f.kind == FLT.CACHE_CORRUPT:
+                    # applied after the step's cache write: silent until
+                    # the poison reaches the slot's logits on a later step
+                    injected += 1
+                    self.cache = decode.corrupt_cache_slot(
+                        self.cfg, self.cache, f.slot)
+        with TraceAnnotation("serve.sync"):
+            # the argmax transfer above already forced the logits; block
+            # on the cache too so every wall-clock stamp below is
+            # post-device-sync
+            jax.block_until_ready(self.cache)
+        with TraceAnnotation("serve.bookkeep"):
+            if injected:
+                self.faults_injected += injected
+                if self._m is not None:
+                    self._m["finj"].inc(injected)
+            if spike_us:
+                time.sleep(spike_us / 1e6)
+            bad: List[int] = []
+            if finite is not None:
+                bad = [i for i, r in enumerate(self.slots)
+                       if r is not None and not bool(finite[i])]
+            new_tokens = 0
+            first_token: List[int] = []
+            completed: List[int] = []
+            for i, r in enumerate(self.slots):
+                if r is None or i in bad:
+                    continue
+                if r.fed >= len(r.prompt):
+                    r.out.append(int(nxt[i]))
+                    new_tokens += 1
+                    if len(r.out) == 1:
+                        r.ttft_seen = True
+                        first_token.append(i)
+                    if len(r.out) >= r.gen:
+                        completed.append(i)
+            if observing:
+                now = self._now_us()
+                wall_us = int((time.perf_counter() - t0) * 1e6)
+                for i in first_token:
+                    r = self.slots[i]
+                    assert r is not None
+                    r.first_token_us = now
+                    if self.spans is not None:
+                        self.spans.emit(SP.REQ_FIRST_TOKEN, ts_us=now,
+                                        prov=SP.req_prov(r.rid),
+                                        step=self.steps, rid=r.rid, slot=i)
+                    if self._m is not None and r.enqueue_us >= 0 \
+                            and not r.ttft_observed:
+                        # once per request: a retried victim keeps its
+                        # original TTFT; the -1 sentinel can never reach the
+                        # histogram because observation happens only at
+                        # emission time
+                        self._m["ttft"].observe(now - r.enqueue_us)
+                        r.ttft_observed = True
+            for i in completed:
+                self._complete(i, SP.FINISHED)
+            for i in bad:
+                self._quarantine(i)
+            if self.spans is not None:
+                self.spans.emit(SP.STEP, prov=SP.step_prov(self.steps),
+                                step=self.steps, dur_us=wall_us,
+                                data=(occupied, len(self.queue), new_tokens,
+                                      prefill_fed))
             if self._m is not None:
-                self._m["fdet"].inc()
-            self._abort_step(observing, t0, spike_ticks, spike_us)
-            return
-        last = logits[:, -1]
-        for f in pending:
-            if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):
-                injected += 1
-                bad_val = jnp.nan if f.kind == FLT.NAN_LOGITS else jnp.inf
-                last = last.at[f.slot].set(bad_val)
-        if self.res is not None and self.res.finite_guard:
-            nxt_d, fin_d = _guarded_argmax()(last)
-            nxt = np.asarray(nxt_d, np.int32)
-            finite = np.asarray(fin_d)
-        else:
-            nxt = np.asarray(jnp.argmax(last, axis=-1), np.int32)
-            finite = None
-        for f in pending:
-            if f.kind == FLT.CACHE_CORRUPT:
-                # applied after the step's cache write: silent until the
-                # poison reaches the slot's logits on a later step
-                injected += 1
-                self.cache = decode.corrupt_cache_slot(self.cfg, self.cache,
-                                                       f.slot)
-        if injected:
-            self.faults_injected += injected
-            if self._m is not None:
-                self._m["finj"].inc(injected)
-        # the argmax transfer above already forced the logits; block on the
-        # cache too so every wall-clock stamp below is post-device-sync
-        jax.block_until_ready(self.cache)
-        if spike_us:
-            time.sleep(spike_us / 1e6)
-        bad: List[int] = []
-        if finite is not None:
-            bad = [i for i, r in enumerate(self.slots)
-                   if r is not None and not bool(finite[i])]
-        new_tokens = 0
-        first_token: List[int] = []
-        completed: List[int] = []
-        for i, r in enumerate(self.slots):
-            if r is None or i in bad:
-                continue
-            if r.fed >= len(r.prompt):
-                r.out.append(int(nxt[i]))
-                new_tokens += 1
-                if len(r.out) == 1:
-                    r.ttft_seen = True
-                    first_token.append(i)
-                if len(r.out) >= r.gen:
-                    completed.append(i)
-        if observing:
-            now = self._now_us()
-            wall_us = int((time.perf_counter() - t0) * 1e6)
-            for i in first_token:
-                r = self.slots[i]
-                assert r is not None
-                r.first_token_us = now
-                if self.spans is not None:
-                    self.spans.emit(SP.REQ_FIRST_TOKEN, ts_us=now,
-                                    prov=SP.req_prov(r.rid), step=self.steps,
-                                    rid=r.rid, slot=i)
-                if self._m is not None and r.enqueue_us >= 0 \
-                        and not r.ttft_observed:
-                    # once per request: a retried victim keeps its original
-                    # TTFT; the -1 sentinel can never reach the histogram
-                    # because observation happens only at emission time
-                    self._m["ttft"].observe(now - r.enqueue_us)
-                    r.ttft_observed = True
-        for i in completed:
-            self._complete(i, SP.FINISHED)
-        for i in bad:
-            self._quarantine(i)
-        if self.spans is not None:
-            self.spans.emit(SP.STEP, prov=SP.step_prov(self.steps),
-                            step=self.steps, dur_us=wall_us,
-                            data=(occupied, len(self.queue), new_tokens,
-                                  prefill_fed))
-        if self._m is not None:
-            m = self._m
-            m["steps"].inc()
-            m["gen"].inc(new_tokens)
-            m["pre"].inc(prefill_fed)
-            m["occ"].set(self.inflight)
-            m["step_h"].observe(wall_us)
-        if self.layers is not None and seg_walls is not None:
-            # one-clock rule: when a span tracer is attached its epoch is
-            # authoritative, so the layer records stamp with the same
-            # post-step `now` as the step span they join to
-            self.layers.on_step(
-                self.steps, self._prof.ops, seg_walls,
-                ts_us=now if self.spans is not None else None)
-        self._health_step(detected=bool(bad))
-        self._tick += 1 + spike_ticks
-        self._enforce_deadlines()
-        self.pos += 1
-        self.steps += 1
+                m = self._m
+                m["steps"].inc()
+                m["gen"].inc(new_tokens)
+                m["pre"].inc(prefill_fed)
+                m["occ"].set(self.inflight)
+                m["step_h"].observe(wall_us)
+            if self.layers is not None and seg_walls is not None:
+                # one-clock rule: when a span tracer is attached its epoch
+                # is authoritative, so the layer records stamp with the
+                # same post-step `now` as the step span they join to
+                self.layers.on_step(
+                    self.steps, self._prof.ops, seg_walls,
+                    ts_us=now if self.spans is not None else None)
+            self._health_step(detected=bool(bad))
+            self._tick += 1 + spike_ticks
+            self._enforce_deadlines()
+            self.pos += 1
+            self.steps += 1
 
     def _quarantine(self, i: int) -> None:
         """Non-finite logits on slot ``i``: zero the slot's cache
@@ -899,7 +935,8 @@ def main():
                                engine_steps=eng.steps)
         assert not problems, problems
         with open(args.spans_out, "w") as f:
-            f.write(SP.to_jsonl(spans_tr.events, stable=args.stable))
+            f.write(SP.to_jsonl(spans_tr.events, stable=args.stable,
+                                epoch_ns=spans_tr.epoch_ns))
         print(f"[serve] {len(spans_tr.events)} span events -> "
               f"{args.spans_out}{' (stable)' if args.stable else ''}")
     if layers is not None:

@@ -188,6 +188,7 @@ def attn_block(cfg: ModelConfig, p, x: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attn")
 def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
                 *, window: int = 0, attn_softcap: float = 0.0,
                 ring: bool = False) -> Tuple[jax.Array, dict]:
@@ -224,20 +225,24 @@ def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
             return qx, scale
         k_q, k_s = _quant(k1)
         v_q, v_s = _quant(v1)
-        kc_q = jax.lax.dynamic_update_slice(cache["k"], k_q, (0, 0, slot, 0))
-        vc_q = jax.lax.dynamic_update_slice(cache["v"], v_q, (0, 0, slot, 0))
-        ks = jax.lax.dynamic_update_slice(cache["k_scale"], k_s,
-                                          (0, 0, slot, 0))
-        vs = jax.lax.dynamic_update_slice(cache["v_scale"], v_s,
-                                          (0, 0, slot, 0))
+        with jax.named_scope("kv_write"):
+            kc_q = jax.lax.dynamic_update_slice(cache["k"], k_q,
+                                                (0, 0, slot, 0))
+            vc_q = jax.lax.dynamic_update_slice(cache["v"], v_q,
+                                                (0, 0, slot, 0))
+            ks = jax.lax.dynamic_update_slice(cache["k_scale"], k_s,
+                                              (0, 0, slot, 0))
+            vs = jax.lax.dynamic_update_slice(cache["v_scale"], v_s,
+                                              (0, 0, slot, 0))
         kc = kc_q.astype(jnp.float32) * ks
         vc = vc_q.astype(jnp.float32) * vs
         new_cache = {"k": kc_q, "v": vc_q, "k_scale": ks, "v_scale": vs}
     else:
-        kc = jax.lax.dynamic_update_slice(
-            cache["k"], k1.astype(cache["k"].dtype), (0, 0, slot, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache["v"], v1.astype(cache["v"].dtype), (0, 0, slot, 0))
+        with jax.named_scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice(
+                cache["k"], k1.astype(cache["k"].dtype), (0, 0, slot, 0))
+            vc = jax.lax.dynamic_update_slice(
+                cache["v"], v1.astype(cache["v"].dtype), (0, 0, slot, 0))
     k_pos = jnp.arange(smax)
     if ring:
         abs_pos = pos - jnp.mod(pos - k_pos, smax)

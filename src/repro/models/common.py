@@ -156,6 +156,7 @@ def sinusoid_pos(seq: int, dim: int, offset: int = 0) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("embed")
 def embed(cfg: ModelConfig, params, tokens: jax.Array) -> jax.Array:
     x = params["embed"][tokens]            # (B, S, D)
     if cfg.name.startswith("gemma"):

@@ -9,6 +9,7 @@ from .config import ModelConfig
 from .params import gated_mlp
 
 
+@jax.named_scope("mlp")
 def mlp_block(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
     h = jnp.einsum("bsd,df->bsf", x, p["wi"])
     if gated_mlp(cfg):

@@ -209,6 +209,7 @@ def moe_block_banked_ep(cfg: ModelConfig, p, x: jax.Array, mesh, model_axis,
     return fn(x, p["router"], p["w1"], p["w2"], wg_param)
 
 
+@jax.named_scope("moe")
 def moe_block(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, Dict]:
     if cfg.moe_dispatch == "banked":
         ep = _ep_context()

@@ -52,6 +52,17 @@ exporters' ``--stable`` mode — normalizes exactly those two fields
 (``ts_us`` becomes the event's ordinal in the stream, ``dur_us`` becomes
 0), making the serialized stream byte-identical across runs; the
 determinism tests and the CI artifact diff rely on this.
+
+Clock
+-----
+
+By default a tracer reads the clock the JAX profiler stamps its host events
+with (the system's real-time clock, ``time.time_ns``), and ``ts_us`` counts
+from the tracer's epoch on it (``SpanTracer.epoch_ns``).  A profiler trace
+(``.xplane.pb``) counts from its ``profile_start_time``, so an event lies at
+``epoch_ns + 1000 * ts_us - profile_start_time`` ns on the trace's timeline,
+beside the engine's ``serve.step`` spans.  ``to_jsonl(..., epoch_ns=...)``
+writes the epoch as the stream's first line.
 """
 from __future__ import annotations
 
@@ -122,17 +133,28 @@ class SpanEvent:
                          tuple(int(v) for v in o["a"]))
 
 
+def profiler_clock() -> float:
+    """Seconds on the clock the profiler stamps host events with."""
+    return time.time_ns() * 1e-9
+
+
 class SpanTracer:
     """Event sink.  The engine accepts ``spans=None`` (the default) and
     guards every emission site with ``if spans is not None`` — the same
-    zero-cost-when-off contract as ``core.trace.Tracer``."""
+    zero-cost-when-off contract as ``core.trace.Tracer``.  ``clock``
+    returns seconds; the default is the profiler's (module docstring)."""
 
     __slots__ = ("events", "_clock", "_t0")
 
-    def __init__(self, clock=time.perf_counter) -> None:
+    def __init__(self, clock=profiler_clock) -> None:
         self.events: List[SpanEvent] = []
         self._clock = clock
         self._t0 = clock()
+
+    @property
+    def epoch_ns(self) -> int:
+        """The instant ``ts_us`` counts from, in ns on the tracer's clock."""
+        return round(self._t0 * 1e9)
 
     def now_us(self) -> int:
         return int((self._clock() - self._t0) * 1e6)
@@ -150,19 +172,24 @@ class SpanTracer:
 # -- serialization -----------------------------------------------------------
 
 
-def to_jsonl(events: Iterable[SpanEvent], stable: bool = False) -> str:
+def to_jsonl(events: Iterable[SpanEvent], stable: bool = False,
+             epoch_ns: Optional[int] = None) -> str:
     """One event per line, in emission order.  ``stable=True`` normalizes
     the wall-clock fields (``ts_us`` -> event ordinal, ``dur_us`` -> 0) so
-    two same-seed runs serialize byte-identically."""
+    two same-seed runs serialize byte-identically.  With ``epoch_ns`` the
+    first line is ``{"epoch_ns": ...}`` (0 when stable)."""
+    head = "" if epoch_ns is None else \
+        json.dumps({"epoch_ns": 0 if stable else epoch_ns}) + "\n"
     if stable:
-        return "".join(ev.to_json(stable_ts=i) + "\n"
-                       for i, ev in enumerate(events))
-    return "".join(ev.to_json() + "\n" for ev in events)
+        return head + "".join(ev.to_json(stable_ts=i) + "\n"
+                              for i, ev in enumerate(events))
+    return head + "".join(ev.to_json() + "\n" for ev in events)
 
 
 def from_jsonl(text: str) -> List[SpanEvent]:
-    return [SpanEvent.from_json(line)
-            for line in text.splitlines() if line.strip()]
+    return [SpanEvent.from_json(line) for line in text.splitlines()
+            if line.strip() and not line.startswith('{"epoch_ns"')]
+
 
 
 # -- span assembly -----------------------------------------------------------
