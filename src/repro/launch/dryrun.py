@@ -133,7 +133,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         record["microbatches"] = mb
         chips = mesh.devices.size
         with mesh:
-            lowered = jax.jit(fn).lower(*args)
+            # a decode step takes the donated cache, as the served one does
+            donate = (1,) if shape.kind == "decode" else ()
+            lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower

@@ -187,14 +187,10 @@ class Engine:
         # tests) whose cache travels in per-group list form; the fused
         # engine pays nothing for the feature existing
         self.layers = layers
-        with TraceAnnotation("serve.init_cache"):
-            if layers is not None:
-                self._prof = decode.make_profiled_serve_step(cfg)
-                self.cache = decode.ProfiledServeStep.init_cache(
-                    cfg, params, slots, max_len)
-            else:
-                self._prof = None
-                self.cache = decode.init_cache(cfg, params, slots, max_len)
+        self._prof = decode.make_profiled_serve_step(cfg) \
+            if layers is not None else None
+        self.cache = self._new_cache()
+        # the cache is donated to the step, which takes over its buffers
         self._step = decode.make_serve_step(cfg)
         self.steps = 0
         self.queue: List[Request] = []
@@ -226,6 +222,14 @@ class Engine:
             else self._own_now_us
         self._m = serve_metrics(metrics, cfg, slots, self.cache) \
             if metrics is not None else None
+
+    def _new_cache(self):
+        with TraceAnnotation("serve.init_cache"):
+            if self._prof is not None:
+                return decode.ProfiledServeStep.init_cache(
+                    self.cfg, self.params, len(self.slots), self.max_len)
+            return decode.init_cache(self.cfg, self.params, len(self.slots),
+                                     self.max_len)
 
     # -- observability helpers ----------------------------------------------
 
@@ -452,14 +456,14 @@ class Engine:
     # -- the engine step -----------------------------------------------------
 
     def _abort_step(self, observing: bool, t0: float, spike_ticks: int,
-                    spike_us: int) -> None:
+                    spike_us: int, occupied: int) -> None:
         """An injected (or caught) step exception: the whole lockstep batch
         loses the step — no tokens, no cache advance, ``pos`` frozen — but
-        the step still counts, ticks, and carries a span."""
+        the step still counts, ticks, and carries a span that records the
+        ``occupied`` slots it started with."""
         self._record_fault()
         if spike_us:
             time.sleep(spike_us / 1e6)
-        occupied = self.inflight
         if observing:
             now = self._now_us()
             wall_us = int((time.perf_counter() - t0) * 1e6)
@@ -475,6 +479,29 @@ class Engine:
         self._tick += 1 + spike_ticks
         self._enforce_deadlines()
         self.steps += 1
+
+    def _step_failed(self, before, fed_slots: List[int]) -> None:
+        """A step raised while running, or while its results were read.  If
+        it never took the cache (``before``), the cache stays as it was and
+        this step's prompt feeds are rolled back.  If it took the donated
+        cache, what came back cannot be trusted: the engine starts over on
+        a fresh cache and requeues every in-flight request, under the retry
+        policy, to be served again from its first token."""
+        self.faults_detected += 1
+        if self._m is not None:
+            self._m["fdet"].inc()
+        if not any(leaf.is_deleted() for leaf in jax.tree.leaves(before)):
+            self.cache = before
+            for i in fed_slots:
+                r = self.slots[i]
+                if r is not None:
+                    r.fed -= 1
+            return
+        self.cache = self._new_cache()
+        self.pos = 0
+        for i, r in enumerate(self.slots):
+            if r is not None:
+                self._requeue(i, "restart:cache_lost")
 
     def step(self) -> None:
         """One engine step, as the ``serve.step`` span of a profiler trace
@@ -517,7 +544,8 @@ class Engine:
             self.faults_detected += n_exc
             if self._m is not None:
                 self._m["fdet"].inc(n_exc)
-            self._abort_step(observing, t0, spike_ticks, spike_us)
+            self._abort_step(observing, t0, spike_ticks, spike_us,
+                             self.inflight)
             return
         with TraceAnnotation("serve.feed"):
             toks = np.zeros((len(self.slots), 1), np.int32)
@@ -545,55 +573,51 @@ class Engine:
             toks_d, pos_d = jax.device_put(
                 (toks, np.array(self.pos, np.int32)))
         seg_walls: Optional[List[float]] = None
-        with TraceAnnotation("serve.dispatch"):
-            try:
+        before = self.cache
+        # a device fault shows where the step is called or, on an
+        # accelerator, only where its results are read: dispatch, sample
+        # and sync fail as one
+        try:
+            with TraceAnnotation("serve.dispatch"):
                 if self._prof is not None:
                     logits, self.cache, seg_walls = self._prof(
                         self.params, self.cache, toks_d, pos_d)
                 else:
                     logits, self.cache = self._step(
                         self.params, self.cache, toks_d, pos_d)
-            except Exception:
-                if self.res is None:
-                    raise
-                # genuine runtime failure: roll back this step's prompt
-                # feeds (no cache was written) and degrade instead of
-                # crashing
-                for i in fed_slots:
-                    r = self.slots[i]
-                    if r is not None:
-                        r.fed -= 1
-                self.faults_detected += 1
-                if self._m is not None:
-                    self._m["fdet"].inc()
-                self._abort_step(observing, t0, spike_ticks, spike_us)
-                return
-        with TraceAnnotation("serve.sample"):
-            for f in pending:
-                if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):
-                    injected += 1
-                    bad_val = jnp.nan if f.kind == FLT.NAN_LOGITS \
-                        else jnp.inf
-                    logits = logits.at[f.slot, -1].set(bad_val)
-            if self.res is not None and self.res.finite_guard:
-                nxt_d, fin_d = sample_guarded_argmax(logits)
-                nxt = np.asarray(nxt_d, np.int32)
-                finite = np.asarray(fin_d)
-            else:
-                nxt = np.asarray(sample_argmax(logits), np.int32)
-                finite = None
-            for f in pending:
-                if f.kind == FLT.CACHE_CORRUPT:
-                    # applied after the step's cache write: silent until
-                    # the poison reaches the slot's logits on a later step
-                    injected += 1
-                    self.cache = decode.corrupt_cache_slot(
-                        self.cfg, self.cache, f.slot)
-        with TraceAnnotation("serve.sync"):
-            # the argmax transfer above already forced the logits; block
-            # on the cache too so every wall-clock stamp below is
-            # post-device-sync
-            jax.block_until_ready(self.cache)
+            with TraceAnnotation("serve.sample"):
+                for f in pending:
+                    if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):
+                        injected += 1
+                        bad_val = jnp.nan if f.kind == FLT.NAN_LOGITS \
+                            else jnp.inf
+                        logits = logits.at[f.slot, -1].set(bad_val)
+                if self.res is not None and self.res.finite_guard:
+                    nxt_d, fin_d = sample_guarded_argmax(logits)
+                    nxt = np.asarray(nxt_d, np.int32)
+                    finite = np.asarray(fin_d)
+                else:
+                    nxt = np.asarray(sample_argmax(logits), np.int32)
+                    finite = None
+                for f in pending:
+                    if f.kind == FLT.CACHE_CORRUPT:
+                        # applied after the step's cache write: silent until
+                        # the poison reaches the slot's logits on a later step
+                        injected += 1
+                        self.cache = decode.corrupt_cache_slot(
+                            self.cfg, self.cache, f.slot)
+            with TraceAnnotation("serve.sync"):
+                # the argmax transfer above already forced the logits; block
+                # on the cache too so every wall-clock stamp below is
+                # post-device-sync
+                jax.block_until_ready(self.cache)
+        except Exception:
+            if self.res is None:
+                raise
+            # genuine runtime failure: degrade instead of crashing
+            self._step_failed(before, fed_slots)
+            self._abort_step(observing, t0, spike_ticks, spike_us, occupied)
+            return
         with TraceAnnotation("serve.bookkeep"):
             if injected:
                 self.faults_injected += injected
@@ -669,15 +693,21 @@ class Engine:
 
     def _quarantine(self, i: int) -> None:
         """Non-finite logits on slot ``i``: zero the slot's cache
-        positions, release the slot, and either requeue the victim with
-        backoff or terminate it when attempts are exhausted."""
-        r = self.slots[i]
-        assert r is not None
+        positions and requeue the victim (:meth:`_requeue`)."""
         self.faults_detected += 1
         if self._m is not None:
             self._m["fdet"].inc()
         self._record_fault()
         self.cache = decode.reset_cache_slot(self.cfg, self.cache, i)
+        self._requeue(i, SP.QUARANTINE_PREFIX + "nonfinite")
+
+    def _requeue(self, i: int, detail: str) -> None:
+        """Release slot ``i`` after a fault and either requeue its request
+        with backoff, to start again from its first token, or terminate it
+        when its attempts are exhausted; ``detail`` names the fault on the
+        retry span."""
+        r = self.slots[i]
+        assert r is not None
         res = self.res
         if r.attempt >= res.max_attempts:
             reason = RES.REASON_FAULT if res.max_attempts == 1 \
@@ -696,8 +726,7 @@ class Engine:
         if self.spans is not None:
             self.spans.emit(SP.REQ_RETRY, prov=SP.req_prov(r.rid),
                             step=self.steps, rid=r.rid, slot=i,
-                            detail=SP.QUARANTINE_PREFIX + "nonfinite",
-                            data=(failed, delay))
+                            detail=detail, data=(failed, delay))
         if self._m is not None:
             self._m["retry"].inc()
             self._m["occ"].set(self.inflight)

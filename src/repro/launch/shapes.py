@@ -112,7 +112,9 @@ def cache_pspecs(cfg: ModelConfig, batch: int, mesh: Mesh,
         n_lead = 1 + (1 if stacked_inner else 0)
         lead = [None] * n_lead
         if name in ("k", "v", "k_scale", "v_scale"):
-            return P(*lead, baxes, hax, None, None)
+            axes = decode.CROSS_AXES if "cross" in path else decode.KV_AXES
+            mesh_axis = {"batch": baxes, "heads": hax}
+            return P(*lead, *(mesh_axis.get(a) for a in axes))
         if name == "h":                     # mamba state (…,B,nh,st,hd)
             return P(*lead, baxes, hax, None, None)
         if name == "conv":                  # (…,B,K-1,CH)

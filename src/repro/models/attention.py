@@ -192,12 +192,15 @@ def attn_block(cfg: ModelConfig, p, x: jax.Array, *,
 def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
                 *, window: int = 0, attn_softcap: float = 0.0,
                 ring: bool = False) -> Tuple[jax.Array, dict]:
-    """x1: (B, 1, D); cache: {'k','v'} (B, Hkv, S_max, dh); pos: scalar.
+    """x1: (B, 1, D); cache: {'k','v'} (B, S_max, Hkv * dh) (int8 adds
+    {'k_scale','v_scale'} (B, S_max, Hkv)); pos: scalar.
 
     ``ring=True`` treats the cache as a circular window buffer (sliding-
     window layers): slot i holds absolute position pos - ((pos - i) mod L).
 
-    Returns (attn output (B,1,D), updated cache).
+    The cache is only read.  Returns (attn output (B,1,D), the new token's
+    rows, each (B, 1, ·) like the cache's), which the caller writes at
+    position ``pos mod S_max`` of its cache.
     """
     b, _, d = x1.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -208,12 +211,12 @@ def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
         cos, sin = rope_freqs(dh, cfg.rope_theta, posv)
         q = apply_rope(q, cos, sin)
         k1 = apply_rope(k1, cos, sin)
-    smax = cache["k"].shape[2]
-    # floor-mod (jnp.mod), NOT lax.rem: C-style rem goes negative for
-    # pos - k_pos < 0 and would mark empty ring slots as valid
-    slot = jnp.mod(pos, smax) if ring else pos
+    smax = cache["k"].shape[1]
+
+    def row(x):                     # (B, Hkv, 1, w) -> (B, 1, Hkv * w)
+        return x.transpose(0, 2, 1, 3).reshape(b, 1, -1)
+
     quantized = "k_scale" in cache
-    new_cache = {}
     if quantized:
         # int8 KV cache: per-token absmax scales (beyond-paper feature)
         def _quant(x):
@@ -225,44 +228,62 @@ def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
             return qx, scale
         k_q, k_s = _quant(k1)
         v_q, v_s = _quant(v1)
-        with jax.named_scope("kv_write"):
-            kc_q = jax.lax.dynamic_update_slice(cache["k"], k_q,
-                                                (0, 0, slot, 0))
-            vc_q = jax.lax.dynamic_update_slice(cache["v"], v_q,
-                                                (0, 0, slot, 0))
-            ks = jax.lax.dynamic_update_slice(cache["k_scale"], k_s,
-                                              (0, 0, slot, 0))
-            vs = jax.lax.dynamic_update_slice(cache["v_scale"], v_s,
-                                              (0, 0, slot, 0))
-        kc = kc_q.astype(jnp.float32) * ks
-        vc = vc_q.astype(jnp.float32) * vs
-        new_cache = {"k": kc_q, "v": vc_q, "k_scale": ks, "v_scale": vs}
+        rows = {"k": row(k_q), "v": row(v_q), "k_scale": row(k_s),
+                "v_scale": row(v_s)}
+        def dequant(n):
+            x = cache[n].astype(jnp.float32).reshape(b, smax, hkv, dh)
+            return (x * cache[n + "_scale"][..., None]).reshape(b, smax, -1)
+        kc, vc = dequant("k"), dequant("v")
+        k_new = k_q.astype(jnp.float32) * k_s
+        v_new = v_q.astype(jnp.float32) * v_s
     else:
-        with jax.named_scope("kv_write"):
-            kc = jax.lax.dynamic_update_slice(
-                cache["k"], k1.astype(cache["k"].dtype), (0, 0, slot, 0))
-            vc = jax.lax.dynamic_update_slice(
-                cache["v"], v1.astype(cache["v"].dtype), (0, 0, slot, 0))
+        dt = cache["k"].dtype
+        k_new, v_new = k1.astype(dt), v1.astype(dt)
+        rows = {"k": row(k_new), "v": row(v_new)}
+        kc, vc = cache["k"], cache["v"]
+    # the cached keys before this token (where the new row goes, the cache
+    # holds an older token or nothing); the new token's own key is attended
+    # as a column of its own
     k_pos = jnp.arange(smax)
     if ring:
+        # floor-mod (jnp.mod), NOT lax.rem: C-style rem goes negative for
+        # pos - k_pos < 0 and would mark empty ring slots as valid
         abs_pos = pos - jnp.mod(pos - k_pos, smax)
-        mask = abs_pos >= 0
+        mask = (abs_pos >= 0) & (abs_pos < pos)
         if window:
             mask &= (pos - abs_pos) < window
     else:
-        mask = k_pos <= pos
+        mask = k_pos < pos
         if window:
             mask &= (pos - k_pos) < window
-    qg = q.reshape(b, hkv, g, 1, dh)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
-                   kc.astype(jnp.float32)) / (dh ** 0.5)
-    if attn_softcap:
-        s = softcap(s, attn_softcap)
-    s = jnp.where(mask[None, None, None, None], s, _NEG)
-    pgs = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", pgs, vc.astype(jnp.float32))
+    qg = q.reshape(b, hkv, g, 1, dh).astype(jnp.float32)
+    # each head's query laid over the row of all heads, zero off its own
+    # lanes: the cache's rows are read as they lie, with no relayout.  The
+    # two matmuls do Hkv times the work of a per-head contraction, 2 x H
+    # flops a cached element, still far below what the MXU does in the time
+    # the read takes.  A per-head contraction over the row split into
+    # (Hkv, dh) costs a relayout of each layer's K and V instead (compiled
+    # for a TPU v5e at dh 64 and 128).
+    own = jnp.eye(hkv, dtype=jnp.float32)
+    q_rows = jnp.einsum("bhgqd,hj->bhgqjd", qg, own).reshape(
+        b, hkv, g, 1, hkv * dh)
+
+    def finish(s):
+        s = s / (dh ** 0.5)
+        return softcap(s, attn_softcap) if attn_softcap else s
+
+    s_old = finish(jnp.einsum("bhgqc,bkc->bhgqk", q_rows,
+                              kc.astype(jnp.float32)))
+    s_old = jnp.where(mask[None, None, None, None], s_old, _NEG)
+    s_new = finish(jnp.einsum("bhgqd,bhkd->bhgqk", qg,
+                              k_new.astype(jnp.float32)))
+    m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
+    p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
+    pv = jnp.einsum("bhgqk,bkc->bhgqc", p_old, vc.astype(jnp.float32))
+    pv = jnp.einsum("bhgqjd,hj->bhgqd", pv.reshape(b, hkv, g, 1, hkv, dh),
+                    own)
+    out = (pv + p_new * v_new[:, :, None].astype(jnp.float32)) \
+        / (p_old.sum(axis=-1, keepdims=True) + p_new)
     out = out.reshape(b, h, 1, dh).transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bse,ed->bsd", out.astype(x1.dtype), p["wo"])
-    if quantized:
-        return out, new_cache
-    return out, {"k": kc, "v": vc}
+    return out, rows

@@ -5,7 +5,7 @@ seq_len-sized cache), not the training step.  Caches are stacked over scan
 groups so the decode HLO also contains a single group body.
 
 Cache layouts:
-  dense/moe : {'k','v'} (G, [layers-per-group,] B, Hkv, L, dh), pos scalar
+  dense/moe : {'k','v'} (G, [layers-per-group,] B, L, Hkv * dh), pos scalar
   vlm       : self caches + precomputed vision cross K/V
   hybrid    : mamba states (O(1)) + shared-attn KV cache
   ssm       : wkv state + shift states (O(1))
@@ -30,17 +30,26 @@ from .params import param_specs
 from .transformer import encode_audio
 
 
-def _kv_shape(cfg: ModelConfig, batch: int, max_len: int):
-    return (batch, cfg.num_kv_heads, max_len, cfg.head_dim)
+# The axes of a cache's attention leaves below their stacking over layer
+# groups.  A self-attention leaf (k, v and int8's k_scale, v_scale) holds a
+# position's K or V of all heads as one row, (B, S_max, Hkv * dh); a cross
+# cache (precomputed, never written) keeps a (position, dh) block a head.
+# The dry-run's partition specs are read from these.
+KV_AXES = ("batch", "position", "heads")
+CROSS_AXES = ("batch", "heads", "position", "head_dim")
+_KV_POSITION = KV_AXES.index("position") - len(KV_AXES)
 
 
 def _kv_entry(cfg: ModelConfig, batch: int, max_len: int):
-    """Self-attention cache entry; int8 mode adds per-token scales."""
-    kv = _kv_shape(cfg, batch, max_len)
-    entry = {"k": kv, "v": kv}
+    """Self-attention cache entry laid out as :data:`KV_AXES`; int8 mode
+    adds per-token scales, one a head."""
+    def shape(per_head: int):
+        size = {"batch": batch, "position": max_len,
+                "heads": cfg.num_kv_heads * per_head}
+        return tuple(size[a] for a in KV_AXES)
+    entry = {"k": shape(cfg.head_dim), "v": shape(cfg.head_dim)}
     if cfg.kv_cache_dtype == "int8":
-        entry["k_scale"] = (batch, cfg.num_kv_heads, max_len, 1)
-        entry["v_scale"] = (batch, cfg.num_kv_heads, max_len, 1)
+        entry["k_scale"] = entry["v_scale"] = shape(1)
     return entry
 
 
@@ -60,7 +69,6 @@ def _stack_shapes(n: int, tree):
 
 def cache_shapes(cfg: ModelConfig, batch: int, max_len: int) -> Any:
     g = cfg.num_groups
-    kv = _kv_shape(cfg, batch, max_len)
     fam = cfg.family
     if fam == "dense" and cfg.local_global:
         local_len = min(max_len, cfg.sliding_window)
@@ -177,8 +185,8 @@ def _cross_decode(cfg: ModelConfig, p, x1, kc, vc):
 
 def _dense_decode(cfg, p, x1, c, pos, window=0, ring=False):
     h = norm(cfg, p["ln1"], x1)
-    a, c_new = A.attn_decode(cfg, p["attn"], h, c, pos, window=window,
-                             attn_softcap=cfg.attn_softcap, ring=ring)
+    a, rows = A.attn_decode(cfg, p["attn"], h, c, pos, window=window,
+                            attn_softcap=cfg.attn_softcap, ring=ring)
     if "ln1_post" in p:
         a = norm(cfg, p["ln1_post"], a)
     x1 = x1 + a
@@ -186,10 +194,13 @@ def _dense_decode(cfg, p, x1, c, pos, window=0, ring=False):
     m = mlp_block(cfg, p["mlp"], h)
     if "ln2_post" in p:
         m = norm(cfg, p["ln2_post"], m)
-    return x1 + m, c_new
+    return x1 + m, rows
 
 
 def _group_decode(cfg: ModelConfig, params, pos):
+    """``step(x1, group params, group cache) -> (x1, new)``: ``new`` holds,
+    for each cache leaf, the new token's K/V rows or the whole new state
+    (cross caches pass through), as :func:`write_cache` takes them."""
     fam = cfg.family
 
     if fam == "dense" and cfg.local_global:
@@ -206,11 +217,11 @@ def _group_decode(cfg: ModelConfig, params, pos):
         def step(x1, gp, gc):
             p = gp["lyr"]
             h = norm(cfg, p["ln1"], x1)
-            a, c = A.attn_decode(cfg, p["attn"], h, gc["lyr"], pos)
+            a, rows = A.attn_decode(cfg, p["attn"], h, gc["lyr"], pos)
             x1 = x1 + a
             h = norm(cfg, p["ln2"], x1)
             y, _ = MOE.moe_block(cfg, p["moe"], h)
-            return x1 + y, {"lyr": c}
+            return x1 + y, {"lyr": rows}
     elif fam == "vlm":
         def step(x1, gp, gc):
             def body(xx, lpc):
@@ -246,8 +257,8 @@ def _group_decode(cfg: ModelConfig, params, pos):
         def step(x1, gp, gc):
             p = gp["lyr"]
             h = norm(cfg, p["ln1"], x1)
-            a, c_self = A.attn_decode(cfg, p["attn"], h, gc["lyr"]["self"],
-                                      pos)
+            a, rows = A.attn_decode(cfg, p["attn"], h, gc["lyr"]["self"],
+                                    pos)
             x1 = x1 + a
             h = norm(cfg, p["ln2"], x1)
             x1 = x1 + _cross_decode(cfg, p["cross"], h,
@@ -255,7 +266,7 @@ def _group_decode(cfg: ModelConfig, params, pos):
                                     gc["lyr"]["cross"]["v"])
             h = norm(cfg, p["ln3"], x1)
             x1 = x1 + mlp_block(cfg, p["mlp"], h)
-            return x1, {"lyr": {"self": c_self, "cross": gc["lyr"]["cross"]}}
+            return x1, {"lyr": {"self": rows, "cross": gc["lyr"]["cross"]}}
     else:
         raise ValueError(fam)
     return step
@@ -264,17 +275,41 @@ def _group_decode(cfg: ModelConfig, params, pos):
 # The named scopes of the served step (``jax.named_scope``: metadata on each
 # operation, no operation of its own).  In a profiler trace an operation of
 # ``jit_serve_step`` carries the innermost of them: ``layers`` is the layer
-# loop itself (slicing each layer's parameters and cache, restacking the new
-# cache), ``attn/kv_write`` the cache write inside attention.
+# loop itself (reading each layer's parameters and cache), ``kv_write`` (in
+# ``layers``; the trace reader's ``attn/kv_write``) the write of every
+# layer's new K/V rows into the cache after the loop.
 SERVE_SCOPES = ("embed", "layers", "attn", "attn/kv_write", "mlp", "moe",
                 "head")
+
+
+def _write_leaf(pos, leaf, new):
+    """One cache leaf after a step.  A state comes back whole and replaces
+    the leaf; a K/V leaf gets back the new token's row (length 1 on its
+    position axis, see :data:`KV_AXES`), written in place at ``pos`` mod
+    the leaf's length: the ring slot of a sliding-window layer, ``pos``
+    itself otherwise."""
+    if new.shape == leaf.shape:
+        return new
+    axis = leaf.ndim + _KV_POSITION
+    start = [0] * leaf.ndim
+    start[axis] = jnp.mod(pos, leaf.shape[axis])
+    return jax.lax.dynamic_update_slice(leaf, new.astype(leaf.dtype), start)
+
+
+def write_cache(cache, new, pos):
+    """The cache after a step, from what the step returned for each leaf
+    (see :func:`_write_leaf`)."""
+    return jax.tree.map(functools.partial(_write_leaf, pos), cache, new)
 
 
 def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos
                ) -> Tuple[jax.Array, Any]:
     """tokens: (B, 1) int32; pos: scalar int32 (next write position).
 
-    Returns (logits (B, 1, V), updated cache).
+    Returns (logits (B, 1, V), updated cache).  The layer loop only reads
+    the cache; each K/V leaf gets the new token's rows of every layer in
+    one write after the loop, so with the cache donated
+    (:func:`make_serve_step`) the step updates it in place.
     """
     x1 = embed(cfg, params, tokens)
     if cfg.family == "audio":
@@ -285,21 +320,21 @@ def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos
 
     def body(carry, gpc):
         gp, gc = gpc
-        xx = carry
-        xx, gc_new = step(xx, gp, gc)
-        return xx, gc_new
+        return step(carry, gp, gc)
 
     with jax.named_scope("layers"):
         if cfg.scan_layers:
-            x1, new_cache = jax.lax.scan(body, x1, (params["blocks"], cache))
+            x1, new = jax.lax.scan(body, x1, (params["blocks"], cache))
         else:
-            new_groups = []
+            per_group = []
             for i in range(cfg.num_groups):
                 gp = jax.tree.map(lambda a: a[i], params["blocks"])
                 gc = jax.tree.map(lambda a: a[i], cache)
                 x1, gc_new = step(x1, gp, gc)
-                new_groups.append(gc_new)
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_groups)
+                per_group.append(gc_new)
+            new = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+        with jax.named_scope("kv_write"):
+            new_cache = write_cache(cache, new, pos)
     with jax.named_scope("head"):
         x1 = norm(cfg, params["final_norm"], x1)
         return lm_logits(cfg, params, x1), new_cache
@@ -308,9 +343,10 @@ def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos
 def cache_max_len(cfg: ModelConfig, cache) -> int:
     """Decoder self-attention cache length (the position-table size)."""
     if cfg.family == "audio":
-        return cache["lyr"]["self"]["k"].shape[-2]
+        return cache["lyr"]["self"]["k"].shape[_KV_POSITION]
     leaves = jax.tree.leaves(cache)
-    return max((l.shape[-2] for l in leaves if l.ndim >= 4), default=1)
+    return max((l.shape[_KV_POSITION] for l in leaves if l.ndim >= 4),
+               default=1)
 
 
 # ---------------------------------------------------------------------------
@@ -328,12 +364,16 @@ def make_serve_step(cfg: ModelConfig):
     comparisons between instrumented and uninstrumented runs of the same
     workload (the serve benchmark measures exactly that differential).
     The program is named ``jit_serve_step`` in traces and compile logs.
+
+    The cache is donated: the returned cache takes over its buffers, and
+    the one passed in is deleted once the call dispatches, so a caller
+    rebinds its cache to the result.
     """
     def step(params, cache, tokens, pos):
         return serve_step(cfg, params, cache, tokens, pos)
 
     step.__name__ = step.__qualname__ = "serve_step"
-    return jax.jit(step)
+    return jax.jit(step, donate_argnums=(1,))
 
 
 def cache_num_bytes(cache) -> int:
@@ -512,13 +552,13 @@ class ProfiledServeStep:
 
         def dense_attn(p, x1, c, pos, window=0, ring=False):
             h = norm(cfg, p["ln1"], x1)
-            a, c_new = A.attn_decode(cfg, p["attn"], h, c, pos,
-                                     window=window,
-                                     attn_softcap=cfg.attn_softcap,
-                                     ring=ring)
+            a, rows = A.attn_decode(cfg, p["attn"], h, c, pos,
+                                    window=window,
+                                    attn_softcap=cfg.attn_softcap,
+                                    ring=ring)
             if "ln1_post" in p:
                 a = norm(cfg, p["ln1_post"], a)
-            return x1 + a, c_new
+            return x1 + a, write_cache(c, rows, pos)
 
         def dense_mlp(p, x1):
             h = norm(cfg, p["ln2"], x1)
@@ -539,8 +579,8 @@ class ProfiledServeStep:
         elif fam == "moe":
             def moe_attn(p, x1, c, pos):
                 h = norm(cfg, p["ln1"], x1)
-                a, c_new = A.attn_decode(cfg, p["attn"], h, c, pos)
-                return x1 + a, c_new
+                a, rows = A.attn_decode(cfg, p["attn"], h, c, pos)
+                return x1 + a, write_cache(c, rows, pos)
 
             def moe_ffn(p, x1):
                 h = norm(cfg, p["ln2"], x1)

@@ -1,6 +1,7 @@
 """Launch layer: shapes, sharding rules, cell skip logic, model flops
 (host-mesh scale — the 512-device path is exercised by dryrun itself)."""
 import pathlib
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ from repro.launch import shapes as SH
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.dryrun import model_flops
 from repro.launch.shapes import SHAPES, cell_supported
-from repro.models import all_names, get_config
+from repro.models import all_names, decode, get_config
 from repro.models import params as MP
 from repro.sharding.rules import (ShardingStrategy, param_pspecs,
                                   sanitize_spec)
@@ -116,6 +117,36 @@ class TestInputSpecs:
             specs = SH.input_specs(cfg, shape, mesh, st)
             leaves = jax.tree.leaves(specs)
             assert leaves and all(hasattr(l, "shape") for l in leaves)
+
+    @pytest.mark.parametrize("arch", all_names())
+    def test_decode_cache_shards_heads_not_positions(self, arch):
+        """On the production mesh the tensor-parallel axis splits a K/V
+        cache leaf along its heads (the Hkv * dh lanes of a self-attention
+        row), never along its positions."""
+        mesh = SimpleNamespace(axis_names=("data", "model"),
+                               devices=np.empty((16, 16)))
+        cfg = get_config(arch)
+        batch = SH.SHAPES["decode_32k"].global_batch
+        specs = jax.tree_util.tree_flatten_with_path(
+            decode.cache_specs(cfg, batch, 64))[0]
+        pspecs = jax.tree.leaves(SH.cache_pspecs(cfg, batch, mesh,
+                                                 ShardingStrategy()),
+                                 is_leaf=lambda x: isinstance(x, P))
+        heads_split = cfg.num_kv_heads % 16 == 0
+        kv = 0
+        for (path, sd), spec in zip(specs, pspecs):
+            keys = [k.key for k in path]
+            if keys[-1] not in ("k", "v", "k_scale", "v_scale"):
+                continue
+            kv += 1
+            axes = decode.CROSS_AXES if "cross" in keys else decode.KV_AXES
+            spec = sanitize_spec(spec, sd.shape, mesh)
+            got = dict(zip(axes, tuple(spec)[len(sd.shape) - len(axes):]))
+            assert got["position"] is None, (keys, spec)
+            assert got["heads"] == ("model" if heads_split else None), \
+                (keys, spec)
+            assert got["batch"] in ("data", ("data",)), (keys, spec)
+        assert kv or cfg.family == "ssm"
 
 
 class TestCompileCache:

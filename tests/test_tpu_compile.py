@@ -11,6 +11,7 @@ entry written for a described chip cannot be read back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,25 +81,113 @@ def test_ssm_scan_rwkv6_heads(one_chip, diag_mode):
                                             _spec(one_chip, (h, dh)))
 
 
-def test_qwen2_serve_step_fits_one_chip(one_chip):
+def _nbytes(tree):
+    return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+
+
+def _compile_qwen2_step(sharding, slots, max_len):
+    """(compiled step, param specs, cache specs) of the full-width qwen2-0.5b
+    serve step for ``slots`` x ``max_len``."""
     cfg = get_config("qwen2-0.5b")
-    slots, max_len = 4, 4096
     params = MP.param_specs(cfg)
     cache = jax.eval_shape(
         functools.partial(decode.init_cache, cfg, batch=slots,
                           max_len=max_len), params)
 
     def place(tree):
-        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype),
+        return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype),
                             tree)
 
     compiled = decode.make_serve_step(cfg).lower(
-        place(params), place(cache), _spec(one_chip, (slots, 1), jnp.int32),
-        _spec(one_chip, (), jnp.int32)).compile()
+        place(params), place(cache), _spec(sharding, (slots, 1), jnp.int32),
+        _spec(sharding, (), jnp.int32)).compile()
+    return compiled, params, cache
+
+
+def test_qwen2_serve_step_fits_one_chip(one_chip):
+    compiled, params, cache = _compile_qwen2_step(one_chip, 4, 4096)
     mem = compiled.memory_analysis()
-    param_bytes = sum(s.size * s.dtype.itemsize
-                      for s in jax.tree.leaves(params))
-    assert mem.argument_size_in_bytes >= param_bytes
+    assert mem.argument_size_in_bytes >= _nbytes(params)
+    # the donated cache comes back in its own buffers
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+_COMPUTATION = re.compile(r"^(ENTRY )?%([\w.\-]+) .*\{$")
+_ARRAY_OP = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([a-z][\w\-]*)\((.*)")
+
+
+def _size(dims):
+    return functools.reduce(lambda a, b: a * b, dims, 1)
+
+
+def _operations(hlo):
+    """Per computation of a compiled module, its array-valued instructions
+    as {name: (dims, opcode, rest of the line)}, the entry's name, and the
+    computations that run as control flow (bodies, conditions, calls)."""
+    comps, entry, cur, flow = {}, None, None, set()
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(2), {})
+            entry = m.group(2) if m.group(1) else entry
+            continue
+        flow.update(re.findall(r"(?:condition|body|to_apply)=%([\w.\-]+)",
+                               line) if " fusion(" not in line else ())
+        m = _ARRAY_OP.match(line)
+        if m and cur is not None:
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            cur[m.group(1)] = (dims, m.group(3), m.group(4))
+    return comps, entry, flow
+
+
+def _update_rows(comps, comp, opcode, rest):
+    """Elements of the update a dynamic-update-slice writes (for a fusion,
+    the one at its root), or None for any other operation."""
+    if opcode == "fusion":
+        comp = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+        roots = [v for v in comps[comp].values()
+                 if v[1] == "dynamic-update-slice"]
+        if len(roots) != 1:
+            return None
+        opcode, rest = roots[0][1], roots[0][2]
+    if opcode != "dynamic-update-slice":
+        return None
+    update = re.findall(r"%([\w.\-]+)", rest)[1]
+    return _size(comps[comp][update][0])
+
+
+@pytest.mark.parametrize("slots,max_len", [(512, 528), (256, 1024)],
+                         ids=["512x528", "256x1024"])
+def test_qwen2_serve_step_updates_the_cache_in_place(one_chip, slots,
+                                                     max_len):
+    """At the benchmark cells' shapes the step takes the donated cache and
+    writes only the new token's rows into it.  No operation the size of the
+    stacked cache runs but those row updates, and inside the layer loop no
+    operation the size of one layer's K or V runs at all: attention reads
+    each layer's rows where they lie in the stacked cache."""
+    compiled, _, cache = _compile_qwen2_step(one_chip, slots, max_len)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    assert mem.temp_size_in_bytes < 1e9, mem.temp_size_in_bytes
+    kv = jax.tree.leaves(cache)
+    stacked = kv[0].shape
+    assert len(kv) == 2 and all(leaf.shape == stacked for leaf in kv)
+    layer = _size(stacked[1:])
+    comps, entry, flow = _operations(compiled.as_text())
+    whole, in_loop = [], []
+    for comp in [entry, *flow]:
+        for name, (dims, opcode, rest) in comps[comp].items():
+            if opcode in ("parameter", "constant", "bitcast",
+                          "get-tuple-element"):
+                continue                # names a buffer, moves nothing
+            if dims == stacked:
+                whole.append((name, _update_rows(comps, comp, opcode, rest)))
+            elif comp != entry and _size(dims) >= layer:
+                in_loop.append((name, opcode))
+    row = _size(stacked) // max_len
+    assert len(whole) == 2 and all(n == row for _, n in whole), whole
+    assert in_loop == []
