@@ -1,4 +1,5 @@
-"""Correctness of served tokens against a family's fp32 reference.
+"""Correctness of served tokens against the fp32 reference that the
+configuration file names (``reference``, by default its ``family``).
 
 For each sampled request the reference runs once, teacher-forced over its
 prompt and served tokens (padded to the cache length, so one compiled shape
@@ -9,12 +10,11 @@ the served token is the reference's argmax.  With ``mm="fp8"`` the same
 forward runs with every product in float8 and the gap is read for the token
 that this control puts first.
 """
-import importlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import reference
 from chipbench.reference.common import MATMULS, f32
 
 ROWS = 8
@@ -46,16 +46,16 @@ def _batch(done, max_len):
 
 def logits(sizes, params, tokens, rows, cols, mm="fp32"):
     """Reference logits at (rows, cols) of ``tokens``: (len(rows), vocab)."""
-    fam = importlib.import_module(f"chipbench.reference.{sizes['family']}")
+    arch = reference.for_config(sizes)
     fn = MATMULS[mm]
     with jax.default_matmul_precision("highest"):
-        layer = jax.jit(lambda p, x: fam.layer(sizes, p, x, fn))
+        layer = jax.jit(lambda p, x: arch.layer(sizes, p, x, fn))
         x = f32(params["embed"][jnp.asarray(tokens)])
         blocks = params["blocks"]["lyr"]
         for i in range(sizes["num_hidden_layers"]):
             x = layer(jax.tree.map(lambda a: a[i], blocks), x)
         top = {k: params[k] for k in ("embed", "final_norm", "lm_head") if k in params}
-        return jax.jit(lambda t, h: fam.head(sizes, t, h, fn))(
+        return jax.jit(lambda t, h: arch.head(sizes, t, h, fn))(
             top, x[jnp.asarray(rows), jnp.asarray(cols)])
 
 

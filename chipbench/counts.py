@@ -4,9 +4,19 @@ These count the algorithm, not what the program does today: attention over
 the live positions of occupied slots only, every weight read once, and each
 expert read only if some token is routed to it.  ``sizes`` is a
 configuration file's dict (published key names).
+
+An architecture's reference module (``chipbench/reference/``) may count its
+own step: ``step_flops`` and ``step_bytes`` here then return what its
+functions of the same names give.  Otherwise the step is counted as a
+decoder of attention with a K/V cache and a SiLU-gated MLP, or routed
+experts where the file has ``num_experts`` (``decoder_step_flops``,
+``decoder_step_bytes``, which such a module may call for the parts it
+shares).
 """
 import json
 import pathlib
+
+from chipbench import reference
 
 BF16 = 2
 
@@ -39,7 +49,7 @@ def matmul_params_per_token(s):
     """Weights a token multiplies: per layer attention + MLP (or router and
     its top-k experts), and the output head once."""
     n_layers, d, _, _, _, _, v = _dims(s)
-    if s["family"] == "moe":
+    if s.get("num_experts"):
         ffn = d * s["num_experts"] + s["num_experts_per_tok"] * expert_params(s)
     else:
         ffn = expert_params(s)
@@ -47,6 +57,19 @@ def matmul_params_per_token(s):
 
 
 def step_flops(s, n_occ, pos):
+    """FLOPs of one step with ``n_occ`` occupied slots at position ``pos``."""
+    own = getattr(reference.for_config(s), "step_flops", None)
+    return own(s, n_occ, pos) if own else decoder_step_flops(s, n_occ, pos)
+
+
+def step_bytes(s, n_occ, pos):
+    """HBM bytes one step with ``n_occ`` occupied slots at position ``pos``
+    needs."""
+    own = getattr(reference.for_config(s), "step_bytes", None)
+    return own(s, n_occ, pos) if own else decoder_step_bytes(s, n_occ, pos)
+
+
+def decoder_step_flops(s, n_occ, pos):
     """FLOPs of one step with ``n_occ`` occupied slots at position ``pos``:
     2 per multiply-add of the weights, and QK^T plus PV over pos + 1 keys."""
     n_layers, _, h, _, dh, _, _ = _dims(s)
@@ -61,7 +84,7 @@ def experts_touched(s, n_occ):
     return e * (1.0 - (1.0 - k / e) ** n_occ)
 
 
-def step_bytes(s, n_occ, pos):
+def decoder_step_bytes(s, n_occ, pos):
     """HBM bytes one step needs: every weight once (the experts touched, the
     output head, the embedding rows looked up when the head is untied), the
     K/V of positions <= pos read and one K/V position written per occupied
@@ -70,7 +93,7 @@ def step_bytes(s, n_occ, pos):
     layer = attn_params(s) + 2 * d                      # + two norm gains
     if s["qkv_bias"]:
         layer += (s["num_attention_heads"] + 2 * hkv) * dh
-    if s["family"] == "moe":
+    if s.get("num_experts"):
         layer += d * s["num_experts"] + experts_touched(s, n_occ) * expert_params(s)
     else:
         layer += expert_params(s)

@@ -6,15 +6,19 @@ correctly by the engine (one position is shared by all slots), so a wave is
 the traffic it serves today: offline batch generation.  The host spans
 (``jax.profiler.TraceAnnotation``) name what the host was doing in a
 device trace: building the engine, admitting, each engine step, and the
-jitted step call inside it.
+jitted step call inside it.  Given the names of instruments (``counters``),
+a wave's engine is built with a ``repro.obs.metrics.MetricsRegistry`` and
+the wave keeps what those instruments read at its end.
 """
 import dataclasses
 import gc
 import time
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import MetricsRegistry
 
 
 @dataclasses.dataclass
@@ -27,6 +31,9 @@ class Wave:
     inflight: np.ndarray        # occupied slots before each step
     done: list                  # (prompt, served tokens) of finished requests
     failed: int                 # requests not finished with all their tokens
+    # name -> the instrument's ``as_json()`` at the wave's end, for each
+    # instrument asked for that the engine made
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def seconds(self):
@@ -45,13 +52,17 @@ def occupancy(prompt_len, gen_len, steps):
 
 def run(serve, cfg, params, requests, max_len,
         on_step: Optional[Callable[[int], None]] = None,
-        max_steps: Optional[int] = None) -> Wave:
+        max_steps: Optional[int] = None,
+        counters: Optional[Sequence[str]] = None) -> Wave:
     """Serve one wave of ``requests`` ((prompt ids, generation length), in
     slot order) on a fresh engine; ``on_step(n)`` runs after step n.  With
-    ``max_steps`` (the warm-up) the wave stops early and counts as failed."""
+    ``max_steps`` (the warm-up) the wave stops early and counts as failed.
+    With ``counters`` the engine gets a metrics registry, and the wave keeps
+    those of its instruments that the engine made."""
     t_start = time.perf_counter()
     with TraceAnnotation("wave.build"):
-        eng = serve.Engine(cfg, params, len(requests), max_len)
+        reg = None if counters is None else MetricsRegistry()
+        eng = serve.Engine(cfg, params, len(requests), max_len, metrics=reg)
     step = eng._step
 
     def jit_call(*args):
@@ -84,9 +95,11 @@ def run(serve, cfg, params, requests, max_len,
     occ, _ = occupancy(p, g, len(t_end))
     if eng.pos > max_len or not np.array_equal(occ, inflight):
         failed = len(reqs)       # truncated, or not the wave's schedule
+    read = {} if reg is None else \
+        {n: reg.get(n).as_json() for n in counters if n in reg.names()}
     # the engine is freed by the cycle collector only (it holds bound
     # methods of itself): collect it now, so one cache pair is ever live
     del eng, jit_call, step
     gc.collect()
     return Wave(p, g, t_start, t_submit, np.array(t_end), np.array(inflight),
-                [(r.prompt, list(r.out)) for r in ok], failed)
+                [(r.prompt, list(r.out)) for r in ok], failed, read)

@@ -8,13 +8,17 @@ configuration, traffic and limit files, makes the weights on the device from
 the seed, warms up the cell's one step shape (compiles, through the
 persistent compile cache in the checkout), then serves whole waves through
 ``repro.launch.serve.Engine`` for at most ``--seconds``.  After the window it
-compares a sample of the served tokens with the family's fp32 reference.
+compares a sample of the served tokens with the fp32 reference that the
+configuration file names.
 The last line of standard output is one JSON object; the numbers compared
 and their limits are also the last lines of standard error.
 
 With ``--trace 1`` the run reports the per-layer metrics instead of the
 end-to-end ones, and records a profiler trace of about three seconds of the
-first wave.
+first wave.  A configuration file that lists ``counters`` (instruments of
+the engine's ``MetricsRegistry``) has them kept for each wave of its
+``--trace 1`` runs, for the per-layer readers; its other runs, and those of
+every other configuration, build the engine without a registry.
 """
 import time
 
@@ -40,7 +44,8 @@ from chipbench import check, counts, driver, gen, stats, xtrace  # noqa: E402
 OUT_DIR = ROOT / ".chipbench"      # traces of --trace 1 runs (not committed)
 TRACE_FIRST_STEP = 20              # the trace starts after this step of wave 0
 TRACE_SECONDS = 3.0
-# ModelConfig field <- configuration-file key, checked against each other
+# ModelConfig field <- configuration-file key, checked against each other;
+# a file's ``repo.checked`` adds pairs of its own
 FIELDS = {"family": "family", "num_layers": "num_hidden_layers",
           "d_model": "hidden_size", "num_heads": "num_attention_heads",
           "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
@@ -72,20 +77,24 @@ def load_cell(root, name):
     cell = cells[name]
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     sizes = json.loads((root / entry["file"]).read_text())
-    traffic = json.loads((HERE / "traffic" / f"{cell['traffic']}.json").read_text())
-    limits = json.loads((HERE / "limits" / f"{name}.json").read_text())
+    traffic = json.loads((root / "chipbench" / "traffic" / f"{cell['traffic']}.json")
+                         .read_text())
+    limits = json.loads((root / "chipbench" / "limits" / f"{name}.json").read_text())
     return bench, cell, sizes, traffic, limits
 
 
 def repo_config(sizes):
     """The program's ModelConfig as the configuration file states it; raises
-    where the program's config disagrees with the file."""
+    where the program's config disagrees with the file, or has no field that
+    the file's ``repo.checked`` names."""
     from repro.models import get_config
     cfg = dataclasses.replace(get_config(sizes["repo"]["config"]),
                               **sizes["repo"]["overrides"])
-    fields = dict(FIELDS, **(MOE_FIELDS if sizes["family"] == "moe" else {}))
-    wrong = {f: (getattr(cfg, f), sizes[k]) for f, k in fields.items()
-             if getattr(cfg, f) != sizes[k]}
+    fields = dict(FIELDS, **(MOE_FIELDS if sizes.get("num_experts") else {}),
+                  **sizes["repo"].get("checked", {}))
+    missing = "<no such field>"
+    wrong = {f: (getattr(cfg, f, missing), sizes[k]) for f, k in fields.items()
+             if getattr(cfg, f, missing) != sizes[k]}
     if wrong:
         raise ValueError(f"program config differs from the file: {wrong}")
     return cfg
@@ -162,6 +171,7 @@ def run_cell(bench, cell, sizes, traffic, limits, seed, seconds, trace,
     cfg = repo_config(sizes)
     max_len, vocab = traffic["max_len"], sizes["vocab_size"]
     dev = jax.devices()[0]
+    counters = sizes.get("counters") if trace else None
     from chipbench import weights
     t_ready = time.perf_counter()
     params = jax.block_until_ready(weights.make(cfg, seed))
@@ -169,7 +179,7 @@ def run_cell(bench, cell, sizes, traffic, limits, seed, seconds, trace,
     # warm-up: the cell's one step shape, on requests the window never sends
     driver.run(serve, cfg, params,
                gen.wave(traffic, np.random.default_rng([seed, 1]), vocab),
-               max_len, max_steps=2)
+               max_len, max_steps=2, counters=counters)
     setup_parts = {"start_to_jax_s": t_ready - t_start,
                    "weights_s": t_weights - t_ready,
                    "warm_up_s": time.perf_counter() - t_weights}
@@ -189,7 +199,8 @@ def run_cell(bench, cell, sizes, traffic, limits, seed, seconds, trace,
     while True:
         requests = gen.wave(traffic, rng, vocab)
         waves.append(driver.run(serve, cfg, params, requests, max_len,
-                                on_step=tracer.on_step if tracer and not waves else None))
+                                on_step=tracer.on_step if tracer and not waves else None,
+                                counters=counters))
         if tracer:
             tracer.stop()
         if window_seconds(waves) + waves[-1].seconds > seconds:

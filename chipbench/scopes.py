@@ -18,6 +18,13 @@ The reduction of a trace:
   inside an operation of ``layers``, else to ``other``.  The
   engine's argmax programs go to ``head`` whole; any other program to
   ``other``;
+- scope paths: the same self time also goes to the operation's path, its
+  scope followed by the scopes the program opened inside it (``moe/experts``
+  for a ``tf_op`` '.../moe/experts/dot_general:'; the parts JAX writes
+  itself, such as ``while``, ``body`` or an einsum's subscripts, left
+  out).  A metric file reads a nested scope by its path (``ms_under``)
+  with no entry in the tables below; the scope it is nested in keeps
+  counting its time;
 - idle time: each instant the first device runs no operation goes to the
   innermost ``serve.*`` phase span the host is in then, to ``serve.step``
   where it is in a step but in none of its phases, or else to "between
@@ -36,6 +43,7 @@ import collections
 import functools
 import heapq
 import pathlib
+import re
 import sys
 
 from chipbench import xtrace
@@ -135,6 +143,26 @@ def scope_of(tf_op):
     return None
 
 
+def _by_jax(part):
+    """Whether a part of an ``op_name`` is one JAX writes itself: a loop or
+    call of its own, a transformation ('jvp(...)'), an einsum's subscripts."""
+    return (part in ("while", "body", "cond", "closed_call", "checkpoint", "remat")
+            or "(" in part or "->" in part or re.fullmatch(r"branch_\d+_fun", part))
+
+
+def scope_path(tf_op):
+    """The innermost named scope of a ``tf_op`` (as ``scope_of`` gives it)
+    followed by the scopes opened inside it, '/'-joined; None where it has
+    no named scope.  The ``op_name``'s last part, the operation, is left
+    out."""
+    parts = (tf_op.rpartition(":")[0] or tf_op).split("/")
+    for i in range(len(parts) - 1, -1, -1):
+        if parts[i] in _SCOPE_OF:
+            inner = [p for p in parts[i + 1:-1] if not _by_jax(p)]
+            return "/".join([_SCOPE_OF[parts[i]]] + inner)
+    return None
+
+
 # -- reduction ---------------------------------------------------------------
 
 def _self_times(ops):
@@ -180,7 +208,8 @@ def _program_of(modules, t):
 
 def reduce(planes, ops_tf):
     """Attribution of one trace: {'window_s', 'steps', 'busy_s', 'scope_s'
-    (seconds per scope and 'other', device mean), 'other_ops' (the five
+    (seconds per scope and 'other', device mean), 'path_s' (the same
+    seconds per scope path, ``scope_path``), 'other_ops' (the five
     operations 'other' holds most of), 'idle_s' (seconds per host span,
     first device), 'programs' (module runs started in the window, device
     mean), 'step_program' (whether ``jit_serve_step`` ran)}.
@@ -203,6 +232,7 @@ def reduce(planes, ops_tf):
     w0, w1 = window[0]
     whole = [(s, e) for s, e in steps if w0 <= s and e <= w1]
     scope_s, programs, busy, first = collections.Counter(), 0, [], None
+    path_s = collections.Counter()
     other_ops = collections.Counter()
     step_program = False
     devices = [p for p in planes if p.name.startswith("/device:TPU")]
@@ -222,18 +252,20 @@ def reduce(planes, ops_tf):
         own, parent = _self_times(ops)
         scope = [None] * len(ops)
         for i in range(len(ops)):          # an enclosing operation comes first
-            prog = _program_of(modules, ops[i][0])
+            prog, path = _program_of(modules, ops[i][0]), None
             if prog in HEAD_PROGRAMS:
                 scope[i] = "head"
             elif prog == STEP_PROGRAM:
                 step_program = True
                 scope[i] = scope_of(tf.get(names[i], ""))
+                path = scope_path(tf.get(names[i], ""))
                 if scope[i] is None:
                     inner = parent[i] >= 0 and scope[parent[i]] == "layers"
                     scope[i] = "layers" if inner or _is_loop(names[i]) else OTHER
             else:
                 scope[i] = OTHER
             scope_s[scope[i]] += own[i] / 1e9
+            path_s[path or scope[i]] += own[i] / 1e9
             if scope[i] == OTHER:
                 other_ops[xtrace.op_name(names[i])] += own[i] / 1e9
         merged = xtrace.merge(ops)
@@ -255,6 +287,7 @@ def reduce(planes, ops_tf):
     return {"window_s": (w1 - w0) / 1e9, "steps": len(whole),
             "busy_s": sum(busy) / n,
             "scope_s": {k: v / n for k, v in scope_s.items()},
+            "path_s": {k: v / n for k, v in path_s.items()},
             "other_ops": [[k, v / n] for k, v in other_ops.most_common(5)],
             "idle_s": dict(idle), "programs": programs / n,
             "step_program": step_program}
@@ -298,6 +331,18 @@ def ms_per_step(w, *scopes):
     return 1e3 * sum(r["scope_s"].get(s, 0.0) for s in scopes) / r["steps"]
 
 
+def ms_under(w, path):
+    """Device ms a step spends in the scope at ``path`` ('moe/experts') and
+    the scopes inside it, by the paths of ``scope_path``; None as for
+    ``ms_per_step``, and where no operation of the window lies under
+    ``path``.  Unlike ``ms_per_step``, 'attn' here holds 'attn/kv_write'."""
+    r = for_window(w)
+    if r is None or not r["step_program"]:
+        return None
+    under = [t for p, t in r["path_s"].items() if p == path or p.startswith(path + "/")]
+    return 1e3 * sum(under) / r["steps"] if under else None
+
+
 def report(r):
     """Lines of text: each scope's and each host span's ms per step."""
     n = r["steps"]
@@ -313,6 +358,9 @@ def report(r):
         out.append(f"  {s:<16} {per(t):10.4f}  {100 * t / r['busy_s']:6.2f}% of busy")
     for name, t in r["other_ops"]:
         out.append(f"    other: {name:<40} {per(t):10.4f}")
+    for p, t in sorted(r["path_s"].items()):
+        if p not in SCOPES + (OTHER,):
+            out.append(f"    path {p:<41} {per(t):10.4f}")
     idle = sum(r["idle_s"].values())
     out.append(f"idle ms {'per step' if n else 'in the window'} "
                f"(idle {idle:.6f} s of the window)")

@@ -1,5 +1,6 @@
 """Small cells for the benchmark's CPU tests: the published configurations'
-families at tiny widths, in bfloat16 as served, with a short traffic mix."""
+architectures at tiny widths, in bfloat16 as served, with a short traffic
+mix."""
 import json
 import pathlib
 import sys
@@ -25,16 +26,17 @@ def sizes(name, **extra):
     """A tiny version of configuration ``name``'s file, with the repo
     overrides that make the program's config agree with it."""
     s = json.loads((ROOT / "chipbench" / "configs" / f"{name}.json").read_text())
-    small = dict(SMALL, num_key_value_heads=2 if s["family"] == "dense" else 4)
-    if s["family"] == "moe":
+    grouped = s["num_key_value_heads"] < s["num_attention_heads"]
+    small = dict(SMALL, num_key_value_heads=2 if grouped else 4)
+    if "num_experts" in s:
         small.update(num_experts=8, num_experts_per_tok=2)
     small.update(extra)
     s.update(small)
     over = dict(s["repo"]["overrides"])
     over.update({REPO_KEYS[k]: v for k, v in small.items()})
-    if s["family"] == "moe":
+    if "num_experts" in s:
         over["moe_capacity_factor"] = s["num_experts"] / s["num_experts_per_tok"]
-    s["repo"] = {"config": s["repo"]["config"], "overrides": over}
+    s["repo"] = dict(s["repo"], overrides=over)
     return s
 
 
