@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from . import banked_conv2d as _bc
 from . import banked_matmul as _bm
 from . import flash_attention as _fa
+from . import grouped_matmul as _gm
 from . import ssm_scan as _ss
 
 
@@ -55,3 +56,17 @@ def decay_scan(q, k, v, w, u=None, chunk: int = 32,
 @functools.partial(jax.jit, static_argnames=("banks",))
 def conv2d(x, w, banks: Tuple[int, int] = (1, 1)) -> jax.Array:
     return _bc.banked_conv2d(x, w, banks=banks, interpret=_interpret())
+
+
+@jax.jit
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   layer=0) -> jax.Array:
+    """Each row of ``lhs`` (M, K) times its group's matrix of ``rhs``
+    (G, K, N), or of ``rhs[layer]`` where ``rhs`` stacks every layer's
+    (L, G, K, N): (M, N) in ``lhs``'s dtype.  The rows are sorted by group;
+    ``group_sizes`` (G,) int32 counts each group's rows, in order, and sums
+    to M."""
+    if rhs.ndim == 3:
+        rhs = rhs[None]
+    return _gm.grouped_matmul(lhs, rhs, group_sizes, jnp.asarray(layer),
+                              interpret=_interpret())

@@ -7,7 +7,8 @@ layer scan and never materializes (S, S) score matrices.  GQA is an einsum
 over a folded group dimension — never a materialized head repeat.
 
 Supports: causal masking, sliding windows (gemma2 local layers), attention
-softcapping, cross attention (whisper / llama-vision), QKV bias (qwen2).
+softcapping, cross attention (whisper / llama-vision), QKV bias (qwen2),
+QK-norm (olmoe).
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import apply_rope, constrain, rope_freqs, softcap
+from .common import apply_rope, constrain, rms_norm, rope_freqs, softcap
 from .config import ModelConfig
 
 _NEG = -1e30
@@ -38,6 +39,12 @@ def qkv_proj(cfg: ModelConfig, p, x: jax.Array,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
+    if cfg.qk_norm:
+        # over the whole projection, all heads at once, before the head
+        # split and RoPE (OLMoE)
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(b, s, h, dh).transpose(0, 2, 1, 3)
     k = k.reshape(b, sk, hkv, dh).transpose(0, 2, 1, 3)
     v = v.reshape(b, sk, hkv, dh).transpose(0, 2, 1, 3)

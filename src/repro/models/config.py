@@ -25,6 +25,7 @@ class ModelConfig:
 
     # attention details
     qkv_bias: bool = False
+    qk_norm: bool = False               # RMSNorm over the flat q and k projections
     rope_theta: float = 1e4
     logit_softcap: float = 0.0          # gemma2 final-logit softcap
     attn_softcap: float = 0.0           # gemma2 attention softcap
@@ -35,7 +36,8 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
-    moe_dispatch: str = "banked"        # banked (paper-style) | gather
+    moe_dispatch: str = "banked"        # banked (paper-style) | gather | grouped
+    moe_norm_topk_prob: bool = True     # top-k gates rescaled to sum to 1
 
     # SSM / hybrid
     ssm_state: int = 0

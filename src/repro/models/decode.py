@@ -277,9 +277,12 @@ def _group_decode(cfg: ModelConfig, params, pos):
 # ``jit_serve_step`` carries the innermost of them: ``layers`` is the layer
 # loop itself (reading each layer's parameters and cache), ``kv_write`` (in
 # ``layers``; the trace reader's ``attn/kv_write``) the write of every
-# layer's new K/V rows into the cache after the loop.
-SERVE_SCOPES = ("embed", "layers", "attn", "attn/kv_write", "mlp", "moe",
-                "head")
+# layer's new K/V rows into the cache after the loop.  Nested in ``attn``,
+# ``qk_norm`` (where the config has it); in ``moe``, its parts ``route``,
+# ``dispatch``, ``experts`` and ``combine`` (``models/moe.py``).
+SERVE_SCOPES = ("embed", "layers", "attn", "attn/kv_write", "attn/qk_norm",
+                "mlp", "moe", "moe/route", "moe/dispatch", "moe/experts",
+                "moe/combine", "head")
 
 
 def _write_leaf(pos, leaf, new):
@@ -317,14 +320,18 @@ def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos
         pe = jax.lax.dynamic_slice_in_dim(table, pos, 1)
         x1 = x1 + pe[None].astype(x1.dtype)
     step = _group_decode(cfg, params, pos)
+    blocks, stacked = params["blocks"], {}
+    if cfg.scan_layers and cfg.family == "moe" and cfg.moe_dispatch == "grouped":
+        with jax.named_scope("layers"):
+            blocks, stacked = MOE.split_stacked(blocks)
 
     def body(carry, gpc):
         gp, gc = gpc
-        return step(carry, gp, gc)
+        return step(carry, MOE.join_stacked(gp, stacked), gc)
 
     with jax.named_scope("layers"):
         if cfg.scan_layers:
-            x1, new = jax.lax.scan(body, x1, (params["blocks"], cache))
+            x1, new = jax.lax.scan(body, x1, (blocks, cache))
         else:
             per_group = []
             for i in range(cfg.num_groups):

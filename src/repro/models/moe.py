@@ -12,6 +12,18 @@ expert-WEIGHT gathers — O(T*D*F) data movement with data-dependent
 indexing, mirroring the cost explosion of the paper's conditional
 bank-select chains (moving the bank to the request instead of the request
 to the bank).
+
+``grouped`` dispatch (dropless, OLMoE): the T*k assignments sorted by
+expert and run through the expert FFN as grouped matmuls over the rows each
+expert got (``kernels.ops.grouped_matmul``, a Pallas kernel: XLA's own
+ragged dot drops the named scope on the TPU), so no assignment is dropped.
+The served step's layer loop hands it each layer's number into the stacked
+expert weights rather than a slice of them (``split_stacked``), which XLA
+would copy out before the kernel reads it.
+
+Inside the ``moe`` scope the served step names its parts: ``route`` (the
+router and its top-k), ``dispatch`` (rows to experts), ``experts`` (the
+expert FFN) and ``combine`` (rows back to tokens, weighted by their gates).
 """
 from __future__ import annotations
 
@@ -21,19 +33,24 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.ops import grouped_matmul
 from .common import activation, constrain
 from . import common as _common
 from .config import ModelConfig
 from .params import gated_mlp
 
 
+@jax.named_scope("route")
 def _router(cfg: ModelConfig, p, x2: jax.Array):
-    """x2: (T, D) -> (probs (T,k), idx (T,k), aux metrics)."""
+    """x2: (T, D) -> (gates (T,k), idx (T,k), aux metrics).  The gates are
+    the top-k softmax probabilities, rescaled to sum to 1 under
+    ``moe_norm_topk_prob``."""
     logits = jnp.einsum("td,de->te", x2.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, cfg.experts_per_token)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     e = cfg.num_experts
     me = jnp.mean(jax.nn.one_hot(top_i, e).sum(1), axis=0)      # load/expert
     pe = probs.mean(axis=0)
@@ -48,6 +65,7 @@ def capacity(cfg: ModelConfig, tokens: int) -> int:
     return max(8, -(-c // 8) * 8)   # pad to lane multiple
 
 
+@jax.named_scope("experts")
 def _expert_ffn(cfg: ModelConfig, p, xe: jax.Array) -> jax.Array:
     """xe: (E, C, D) -> (E, C, D): dense over the leading expert 'banks'."""
     xe = constrain(xe, "experts", "capacity", None)
@@ -73,31 +91,97 @@ def moe_block_banked(cfg: ModelConfig, p, x: jax.Array
     x2 = x.reshape(t, d)
     top_p, top_i, aux = _router(cfg, p, x2)
 
-    # flat (T*k,) assignment stream, token-major; position inside each
-    # expert's capacity buffer = number of earlier assignments to it.
-    eid = top_i.reshape(t * k)
-    gate = top_p.reshape(t * k)
-    oh = jax.nn.one_hot(eid, e, dtype=jnp.int32)                # (T*k, E)
-    pos = (jnp.cumsum(oh, axis=0) - oh)                         # exclusive
-    pos = jnp.take_along_axis(pos, eid[:, None], axis=1)[:, 0]  # (T*k,)
-    keep = pos < cap
-    pos_c = jnp.minimum(pos, cap - 1)
+    with jax.named_scope("dispatch"):
+        # flat (T*k,) assignment stream, token-major; position inside each
+        # expert's capacity buffer = number of earlier assignments to it.
+        eid = top_i.reshape(t * k)
+        gate = top_p.reshape(t * k)
+        oh = jax.nn.one_hot(eid, e, dtype=jnp.int32)            # (T*k, E)
+        pos = (jnp.cumsum(oh, axis=0) - oh)                     # exclusive
+        pos = jnp.take_along_axis(pos, eid[:, None], axis=1)[:, 0]
+        keep = pos < cap
+        pos_c = jnp.minimum(pos, cap - 1)
 
-    # perf iteration 4: expert-leading (E, cap, D) buffer with an explicit
-    # expert sharding — the scatter target lives on the expert's owner
-    # device (bank = device), never replicated.  Dropped tokens scatter
-    # zeros onto the last slot (add-safe).
-    x_rep = jnp.repeat(x2, k, axis=0)                           # (T*k, D)
-    upd = x_rep * keep[:, None].astype(x.dtype)
-    buf = constrain(jnp.zeros((e, cap, d), x.dtype),
-                    "experts", "capacity", None)
-    buf = buf.at[eid, pos_c].add(upd)
-    buf = constrain(buf, "experts", "capacity", None)
+        # perf iteration 4: expert-leading (E, cap, D) buffer with an
+        # explicit expert sharding — the scatter target lives on the
+        # expert's owner device (bank = device), never replicated.  Dropped
+        # tokens scatter zeros onto the last slot (add-safe).
+        x_rep = jnp.repeat(x2, k, axis=0)                       # (T*k, D)
+        upd = x_rep * keep[:, None].astype(x.dtype)
+        buf = constrain(jnp.zeros((e, cap, d), x.dtype),
+                        "experts", "capacity", None)
+        buf = buf.at[eid, pos_c].add(upd)
+        buf = constrain(buf, "experts", "capacity", None)
     ye = _expert_ffn(cfg, p, buf)
-    y_rows = ye[eid, pos_c]                                     # (T*k, D)
-    y_rows = (y_rows.astype(jnp.float32)
-              * (gate * keep.astype(jnp.float32))[:, None])
-    y2 = y_rows.reshape(t, k, d).sum(axis=1)
+    with jax.named_scope("combine"):
+        y_rows = ye[eid, pos_c]                                 # (T*k, D)
+        y_rows = (y_rows.astype(jnp.float32)
+                  * (gate * keep.astype(jnp.float32))[:, None])
+        y2 = y_rows.reshape(t, k, d).sum(axis=1)
+    return y2.astype(x.dtype).reshape(b, s, d), aux
+
+
+EXPERT_WEIGHTS = ("w1", "wg", "w2")
+
+
+def split_stacked(blocks):
+    """A layer loop's parameters for the grouped dispatch: ``blocks`` with
+    each stacked expert weight (L, E, ...) replaced by the layer numbers
+    0..L-1, and the weights themselves (:func:`join_stacked` puts them
+    back)."""
+    moe = blocks["lyr"]["moe"]
+    stacked = {n: moe[n] for n in EXPERT_WEIGHTS if n in moe}
+    layers = jnp.arange(moe["w1"].shape[0], dtype=jnp.int32)
+    lyr = dict(blocks["lyr"], moe=dict(moe, **{n: layers for n in stacked}))
+    return dict(blocks, lyr=lyr), stacked
+
+
+def join_stacked(gp, stacked):
+    """One layer's parameters as the loop slices them from
+    :func:`split_stacked`'s ``blocks``: each expert weight becomes (every
+    layer's weights, this layer's number)."""
+    if not stacked:
+        return gp
+    moe = gp["lyr"]["moe"]
+    moe = dict(moe, **{n: (w, moe[n]) for n, w in stacked.items()})
+    return dict(gp, lyr=dict(gp["lyr"], moe=moe))
+
+
+def _expert_matmul(rows, w, sizes):
+    """``w``: one layer's expert weights (E, K, N), or (every layer's
+    (L, E, K, N), this layer's number)."""
+    weights, layer = w if isinstance(w, tuple) else (w, 0)
+    return grouped_matmul(rows, weights, sizes, layer)
+
+
+def moe_block_grouped(cfg: ModelConfig, p, x: jax.Array
+                      ) -> Tuple[jax.Array, Dict]:
+    """x: (B, S, D).  Dropless dispatch: the T*k assignments sorted by
+    expert, the expert FFN as grouped matmuls over the rows each expert got
+    (group sizes from the routing), the rows put back in assignment order,
+    weighted by their gates and summed over each token's k."""
+    b, s, d = x.shape
+    t = b * s
+    e, k = cfg.num_experts, cfg.experts_per_token
+    x2 = x.reshape(t, d)
+    top_p, top_i, aux = _router(cfg, p, x2)
+    with jax.named_scope("dispatch"):
+        eid = top_i.reshape(t * k)
+        order = jnp.argsort(eid, stable=True)                   # by expert
+        rows = x2[order // k]                                   # (T*k, D)
+        sizes = jnp.bincount(eid, length=e).astype(jnp.int32)   # (E,)
+    with jax.named_scope("experts"):
+        h = _expert_matmul(rows, p["w1"], sizes)
+        if gated_mlp(cfg):
+            h = activation(cfg, _expert_matmul(rows, p["wg"], sizes)) * h
+        else:
+            h = activation(cfg, h)
+        out = _expert_matmul(h, p["w2"], sizes)                 # (T*k, D)
+    with jax.named_scope("combine"):
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))               # inverse
+        y_rows = out[back].astype(jnp.float32) * top_p.reshape(t * k, 1)
+        y2 = y_rows.reshape(t, k, d).sum(axis=1)
     return y2.astype(x.dtype).reshape(b, s, d), aux
 
 
@@ -211,6 +295,8 @@ def moe_block_banked_ep(cfg: ModelConfig, p, x: jax.Array, mesh, model_axis,
 
 @jax.named_scope("moe")
 def moe_block(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, Dict]:
+    if cfg.moe_dispatch == "grouped":
+        return moe_block_grouped(cfg, p, x)
     if cfg.moe_dispatch == "banked":
         ep = _ep_context()
         if ep is not None and cfg.num_experts % ep[3] == 0:

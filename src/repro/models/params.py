@@ -53,6 +53,9 @@ def attn_shapes(cfg: ModelConfig, cross: bool = False) -> Dict[str, Any]:
         s["bq"] = _leaf((h * dh,), "zeros")
         s["bk"] = _leaf((hkv * dh,), "zeros")
         s["bv"] = _leaf((hkv * dh,), "zeros")
+    if cfg.qk_norm:
+        s["q_norm"] = _leaf((h * dh,), "ones")
+        s["k_norm"] = _leaf((hkv * dh,), "ones")
     return s
 
 

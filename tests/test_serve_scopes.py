@@ -73,9 +73,10 @@ def test_every_operation_of_the_step_carries_a_scope(arch):
     inserted = [(n, op) for n, op, o in ops if o is None]
     assert len(inserted) <= 12, inserted
     found = {p for _, _, o in ops if o for p in o.split("/") if p in SCOPE_PARTS}
-    want = {"embed", "layers", "attn", "kv_write", "head",
-            "moe" if cfg.family == "moe" else "mlp"}
-    assert found == want
+    want = {"embed", "layers", "attn", "kv_write", "head"}
+    want |= {"moe", "route", "dispatch", "experts"} if cfg.family == "moe" else {"mlp"}
+    # the weighting of ``combine`` may fuse into the residual add after it
+    assert found - {"combine"} == want
 
 
 def _xplane(trace_dir):

@@ -1,5 +1,6 @@
-"""Compile the main-path kernels and the full-width serve step for one TPU
-v5e chip, described rather than attached (nothing runs).
+"""Compile the main-path kernels and the full-width serve steps (qwen2-0.5b,
+and OLMoE-1B-7B-0924 as its benchmark cell serves it) for one TPU v5e chip,
+described rather than attached (nothing runs).
 
 The TPU compiler refuses what the Pallas interpreter accepts: blocks not
 aligned to the tiling, primitives the kernel language lacks, programs that
@@ -9,6 +10,7 @@ the same tests and only the worker that runs this file loads the TPU
 library.  The persistent compilation cache is off around these compiles: an
 entry written for a described chip cannot be read back without one.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import banked_matmul as BM
 from repro.kernels import flash_attention as FA
+from repro.kernels import ops
 from repro.kernels import ssm_scan as SS
 from repro.models import decode, get_config
 from repro.models import params as MP
@@ -81,6 +84,17 @@ def test_ssm_scan_rwkv6_heads(one_chip, diag_mode):
                                             _spec(one_chip, (h, dh)))
 
 
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["gate_up", "down"])
+def test_grouped_matmul_olmoe_experts(one_chip, monkeypatch, k, n):
+    """The olmoe cell's expert matmuls: 64 slots' top-8 rows over 64
+    experts, compiled as the Pallas kernel the chip runs."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    hlo = ops.grouped_matmul.lower(
+        _spec(one_chip, (512, k)), _spec(one_chip, (64, k, n)),
+        _spec(one_chip, (64,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
 def _nbytes(tree):
     return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
 
@@ -102,6 +116,32 @@ def _compile_qwen2_step(sharding, slots, max_len):
         place(params), place(cache), _spec(sharding, (slots, 1), jnp.int32),
         _spec(sharding, (), jnp.int32)).compile()
     return compiled, params, cache
+
+
+def test_olmoe_0924_serve_step_fits_one_chip(one_chip, monkeypatch):
+    """The olmoe cell's step (8 layers at published widths, QK-norm, plain
+    gates, grouped experts) for 64 x 1024: weights and donated cache on one
+    chip, the expert matmuls as kernels reading the stacked weights."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), num_layers=8, norm_eps=1e-5,
+                              qk_norm=True, moe_norm_topk_prob=False,
+                              moe_dispatch="grouped")
+    params = MP.param_specs(cfg)
+    cache = jax.eval_shape(functools.partial(decode.init_cache, cfg, batch=64,
+                                             max_len=1024), params)
+    place = functools.partial(jax.tree.map, lambda s: _spec(one_chip, s.shape, s.dtype))
+    compiled = decode.make_serve_step(cfg).lower(
+        place(params), place(cache), _spec(one_chip, (64, 1), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    # the kernels read each layer's experts out of the stacked weights: no
+    # layer's slice of them (64 x 2048 x 1024 bf16) is copied out first
+    assert mem.temp_size_in_bytes < 64 * 2048 * 1024 * 2
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
 
 
 def test_qwen2_serve_step_fits_one_chip(one_chip):
