@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from . import banked_conv2d as _bc
 from . import banked_matmul as _bm
+from . import decode_attention as _da
 from . import flash_attention as _fa
 from . import grouped_matmul as _gm
 from . import ssm_scan as _ss
@@ -70,3 +71,15 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         rhs = rhs[None]
     return _gm.grouped_matmul(lhs, rhs, group_sizes, jnp.asarray(layer),
                               interpret=_interpret())
+
+
+@jax.jit
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     lens: jax.Array, layer=0):
+    """One new query a head, q (B, H, dh), against the cached positions
+    ``< lens[b]`` of each slot of ``k[layer]``, ``v[layer]``, where ``k`` and
+    ``v`` stack every layer's (L, B, S_max, Hkv * dh) and are read in place:
+    (acc (B, H, dh), m (B, H), l (B, H)), f32, the softmax's unnormalised
+    sum, running max and denominator."""
+    return _da.decode_attention(q, k, v, lens, jnp.asarray(layer),
+                                interpret=_interpret())

@@ -222,6 +222,16 @@ class Engine:
             else self._own_now_us
         self._m = serve_metrics(metrics, cfg, slots, self.cache) \
             if metrics is not None else None
+        if metrics is not None:
+            # their ratio over the steps is the share of the cache that
+            # decode attention attends (each slot below its length)
+            metrics.gauge("serve_kv_positions",
+                          "K/V positions of one layer's cache "
+                          "(slots x max_len)").set(slots * max_len)
+            self._m["kv_live"] = metrics.counter(
+                "serve_kv_positions_live_total",
+                "K/V positions of one layer's cache below the slots' "
+                "lengths, summed over steps")
 
     def _new_cache(self):
         with TraceAnnotation("serve.init_cache"):
@@ -549,12 +559,16 @@ class Engine:
             return
         with TraceAnnotation("serve.feed"):
             toks = np.zeros((len(self.slots), 1), np.int32)
+            # the cached positions each slot attends: all before ``pos``
+            # for an occupied slot, none for a free one
+            lens = np.zeros(len(self.slots), np.int32)
             prefill_started: List[int] = []
             fed_slots: List[int] = []
             prefill_fed = 0
             for i, r in enumerate(self.slots):
                 if r is None:
                     continue
+                lens[i] = self.pos
                 if r.fed < len(r.prompt):
                     if r.fed == 0:
                         prefill_started.append(r.rid)
@@ -570,8 +584,10 @@ class Engine:
                                     step=self.steps, rid=rid)
             occupied = self.inflight
             # host arrays go up as transfers, with no program of their own
-            toks_d, pos_d = jax.device_put(
-                (toks, np.array(self.pos, np.int32)))
+            toks_d, pos_d, lens_d = jax.device_put(
+                (toks, np.array(self.pos, np.int32), lens))
+            if self._m is not None:
+                self._m["kv_live"].inc(int(lens.sum()))
         seg_walls: Optional[List[float]] = None
         before = self.cache
         # a device fault shows where the step is called or, on an
@@ -584,7 +600,7 @@ class Engine:
                         self.params, self.cache, toks_d, pos_d)
                 else:
                     logits, self.cache = self._step(
-                        self.params, self.cache, toks_d, pos_d)
+                        self.params, self.cache, toks_d, pos_d, lens_d)
             with TraceAnnotation("serve.sample"):
                 for f in pending:
                     if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):

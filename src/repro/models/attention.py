@@ -18,6 +18,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import decode_attention as DA
+from ..kernels.ops import decode_attention
 from .common import apply_rope, constrain, rms_norm, rope_freqs, softcap
 from .config import ModelConfig
 
@@ -195,19 +197,56 @@ def attn_block(cfg: ModelConfig, p, x: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
+def kernel_serves(*, window: int = 0, attn_softcap: float = 0.0,
+                  ring: bool = False, quantized: bool = False) -> bool:
+    """Whether :func:`attn_decode` runs the decode-attention kernel (reads
+    each slot's live rows only) rather than the jnp path (every position,
+    masked): the kernel has no ring buffer, window, softcap or int8 cache."""
+    return not (ring or window or attn_softcap or quantized)
+
+
+# The share of a layer's cache, counted in the kernel's chunks, up to which
+# the kernel takes less time than the einsums reading all of it, by the
+# kernel's tiles (:func:`~repro.kernels.decode_attention.one_query_tiles`).
+# From a step's time over every layer on a TPU v5e at the benchmark cells'
+# shapes (PERF.md §6): several query rows sharing a tile on the MXU (qwen2),
+# a fixed 1.05-1.25 ms plus 8-12 ms per whole cache read, against the
+# einsums' 5.4-5.9 ms, cross at 0.37-0.54; one query a tile on the vector
+# unit (OLMoE), 0.56-0.65 ms plus 8-9 ms against 6.66 ms, at about 0.7.
+# The einsums serve a step above the share; they and the shares go once
+# the kernel reads its rows at the einsums' rate.
+KERNEL_MAX_READ_SHARE = {"shared": 0.4, "one_query": 0.7}
+
+
+def _kernel_reads(lens, width: int, itemsize: int, smax: int):
+    """Positions of one layer's cache the kernel reads for slot lengths
+    ``lens``: each slot's chunks up to its length."""
+    rows = DA.block_rows(width, itemsize, smax)
+    return ((lens + rows - 1) // rows * rows).sum()
+
+
 @jax.named_scope("attn")
 def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
-                *, window: int = 0, attn_softcap: float = 0.0,
-                ring: bool = False) -> Tuple[jax.Array, dict]:
+                *, lens: Optional[jax.Array] = None, window: int = 0,
+                attn_softcap: float = 0.0, ring: bool = False
+                ) -> Tuple[jax.Array, dict]:
     """x1: (B, 1, D); cache: {'k','v'} (B, S_max, Hkv * dh) (int8 adds
-    {'k_scale','v_scale'} (B, S_max, Hkv)); pos: scalar.
+    {'k_scale','v_scale'} (B, S_max, Hkv)), or every layer's (L, B, S_max,
+    ·) with the layer's number under 'layer'; pos: scalar, the new token's
+    position; lens: (B,) int32, the cached positions each slot attends
+    (``< lens[b]``; default ``pos`` for every slot).
 
     ``ring=True`` treats the cache as a circular window buffer (sliding-
-    window layers): slot i holds absolute position pos - ((pos - i) mod L).
+    window layers): slot i holds absolute position pos - ((pos - i) mod L),
+    and attends those below ``pos``.
 
-    The cache is only read.  Returns (attn output (B,1,D), the new token's
-    rows, each (B, 1, ·) like the cache's), which the caller writes at
-    position ``pos mod S_max`` of its cache.
+    The cache is only read: where :func:`kernel_serves` and the slots'
+    lengths leave enough of the cache unread (:data:`KERNEL_MAX_READ_SHARE`),
+    by the decode-attention kernel, which copies each slot's rows below its
+    length from the layer where it lies; else by einsums over every
+    position.  Returns (attn output (B,1,D), the new token's rows, each
+    (B, 1, ·) like the cache's), which the caller writes at position
+    ``pos mod S_max`` of its cache.
     """
     b, _, d = x1.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -218,7 +257,14 @@ def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
         cos, sin = rope_freqs(dh, cfg.rope_theta, posv)
         q = apply_rope(q, cos, sin)
         k1 = apply_rope(k1, cos, sin)
-    smax = cache["k"].shape[1]
+    if lens is None:
+        lens = jnp.full((b,), pos, jnp.int32)
+    layer = cache.get("layer")
+
+    def leaf(n):                    # one layer's (B, S_max, ·)
+        return cache[n] if layer is None else cache[n][layer]
+
+    smax = cache["k"].shape[-2]
 
     def row(x):                     # (B, Hkv, 1, w) -> (B, 1, Hkv * w)
         return x.transpose(0, 2, 1, 3).reshape(b, 1, -1)
@@ -237,60 +283,80 @@ def attn_decode(cfg: ModelConfig, p, x1: jax.Array, cache: dict, pos,
         v_q, v_s = _quant(v1)
         rows = {"k": row(k_q), "v": row(v_q), "k_scale": row(k_s),
                 "v_scale": row(v_s)}
-        def dequant(n):
-            x = cache[n].astype(jnp.float32).reshape(b, smax, hkv, dh)
-            return (x * cache[n + "_scale"][..., None]).reshape(b, smax, -1)
-        kc, vc = dequant("k"), dequant("v")
         k_new = k_q.astype(jnp.float32) * k_s
         v_new = v_q.astype(jnp.float32) * v_s
     else:
         dt = cache["k"].dtype
         k_new, v_new = k1.astype(dt), v1.astype(dt)
         rows = {"k": row(k_new), "v": row(v_new)}
-        kc, vc = cache["k"], cache["v"]
-    # the cached keys before this token (where the new row goes, the cache
-    # holds an older token or nothing); the new token's own key is attended
-    # as a column of its own
-    k_pos = jnp.arange(smax)
-    if ring:
-        # floor-mod (jnp.mod), NOT lax.rem: C-style rem goes negative for
-        # pos - k_pos < 0 and would mark empty ring slots as valid
-        abs_pos = pos - jnp.mod(pos - k_pos, smax)
-        mask = (abs_pos >= 0) & (abs_pos < pos)
-        if window:
-            mask &= (pos - abs_pos) < window
-    else:
-        mask = k_pos < pos
-        if window:
-            mask &= (pos - k_pos) < window
     qg = q.reshape(b, hkv, g, 1, dh).astype(jnp.float32)
-    # each head's query laid over the row of all heads, zero off its own
-    # lanes: the cache's rows are read as they lie, with no relayout.  The
-    # two matmuls do Hkv times the work of a per-head contraction, 2 x H
-    # flops a cached element, still far below what the MXU does in the time
-    # the read takes.  A per-head contraction over the row split into
-    # (Hkv, dh) costs a relayout of each layer's K and V instead (compiled
-    # for a TPU v5e at dh 64 and 128).
-    own = jnp.eye(hkv, dtype=jnp.float32)
-    q_rows = jnp.einsum("bhgqd,hj->bhgqjd", qg, own).reshape(
-        b, hkv, g, 1, hkv * dh)
 
     def finish(s):
         s = s / (dh ** 0.5)
         return softcap(s, attn_softcap) if attn_softcap else s
 
-    s_old = finish(jnp.einsum("bhgqc,bkc->bhgqk", q_rows,
-                              kc.astype(jnp.float32)))
-    s_old = jnp.where(mask[None, None, None, None], s_old, _NEG)
+    # the new token's own key is attended as a column of its own (where its
+    # row goes, the cache holds an older token or nothing)
     s_new = finish(jnp.einsum("bhgqd,bhkd->bhgqk", qg,
                               k_new.astype(jnp.float32)))
-    m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
-    p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
-    pv = jnp.einsum("bhgqk,bkc->bhgqc", p_old, vc.astype(jnp.float32))
-    pv = jnp.einsum("bhgqjd,hj->bhgqd", pv.reshape(b, hkv, g, 1, hkv, dh),
-                    own)
-    out = (pv + p_new * v_new[:, :, None].astype(jnp.float32)) \
-        / (p_old.sum(axis=-1, keepdims=True) + p_new)
+
+    def kernel():
+        kc, vc, n = cache["k"], cache["v"], layer
+        if n is None:
+            kc, vc, n = kc[None], vc[None], 0
+        acc, m, l = decode_attention(q.reshape(b, h, dh), kc, vc, lens, n)
+        acc = acc.reshape(b, hkv, g, 1, dh)
+        m, l = m.reshape(b, hkv, g, 1, 1), l.reshape(b, hkv, g, 1, 1)
+        m_all = jnp.maximum(m, s_new)
+        alpha, p_new = jnp.exp(m - m_all), jnp.exp(s_new - m_all)
+        return (alpha * acc + p_new * v_new[:, :, None].astype(jnp.float32)) \
+            / (alpha * l + p_new)
+
+    def einsums():
+        if quantized:
+            def dequant(n):
+                x = leaf(n).astype(jnp.float32).reshape(b, smax, hkv, dh)
+                return (x * leaf(n + "_scale")[..., None]).reshape(b, smax, -1)
+            kc, vc = dequant("k"), dequant("v")
+        else:
+            kc, vc = leaf("k"), leaf("v")
+        k_pos = jnp.arange(smax)
+        if ring:
+            # floor-mod (jnp.mod), NOT lax.rem: C-style rem goes negative
+            # for pos - k_pos < 0 and would mark empty ring slots as valid
+            abs_pos = pos - jnp.mod(pos - k_pos, smax)
+            mask = ((abs_pos >= 0) & (abs_pos < pos))[None]
+            if window:
+                mask &= (pos - abs_pos) < window
+        else:
+            mask = k_pos[None] < lens[:, None]
+            if window:
+                mask &= (pos - k_pos) < window
+        # each head's query laid over the row of all heads, zero off its own
+        # lanes: the cache's rows are read as they lie, with no relayout, at
+        # Hkv times the matmul work of a per-head contraction
+        own = jnp.eye(hkv, dtype=jnp.float32)
+        q_rows = jnp.einsum("bhgqd,hj->bhgqjd", qg, own).reshape(
+            b, hkv, g, 1, hkv * dh)
+        s_old = finish(jnp.einsum("bhgqc,bkc->bhgqk", q_rows,
+                                  kc.astype(jnp.float32)))
+        s_old = jnp.where(mask[:, None, None, None], s_old, _NEG)
+        m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
+        p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
+        pv = jnp.einsum("bhgqk,bkc->bhgqc", p_old, vc.astype(jnp.float32))
+        pv = jnp.einsum("bhgqjd,hj->bhgqd",
+                        pv.reshape(b, hkv, g, 1, hkv, dh), own)
+        return (pv + p_new * v_new[:, :, None].astype(jnp.float32)) \
+            / (p_old.sum(axis=-1, keepdims=True) + p_new)
+
+    if kernel_serves(window=window, attn_softcap=attn_softcap, ring=ring,
+                     quantized=quantized):
+        share = KERNEL_MAX_READ_SHARE[
+            "one_query" if DA.one_query_tiles(h, hkv, dh) else "shared"]
+        reads = _kernel_reads(lens, hkv * dh, cache["k"].dtype.itemsize, smax)
+        out = jax.lax.cond(reads < share * b * smax, kernel, einsums)
+    else:
+        out = einsums()
     out = out.reshape(b, h, 1, dh).transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bse,ed->bsd", out.astype(x1.dtype), p["wo"])
     return out, rows

@@ -14,6 +14,7 @@ Cache layouts:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -183,10 +184,11 @@ def _cross_decode(cfg: ModelConfig, p, x1, kc, vc):
 # ---------------------------------------------------------------------------
 
 
-def _dense_decode(cfg, p, x1, c, pos, window=0, ring=False):
+def _dense_decode(cfg, p, x1, c, pos, lens=None, window=0, ring=False):
     h = norm(cfg, p["ln1"], x1)
-    a, rows = A.attn_decode(cfg, p["attn"], h, c, pos, window=window,
-                            attn_softcap=cfg.attn_softcap, ring=ring)
+    a, rows = A.attn_decode(cfg, p["attn"], h, c, pos, lens=lens,
+                            window=window, attn_softcap=cfg.attn_softcap,
+                            ring=ring)
     if "ln1_post" in p:
         a = norm(cfg, p["ln1_post"], a)
     x1 = x1 + a
@@ -197,37 +199,44 @@ def _dense_decode(cfg, p, x1, c, pos, window=0, ring=False):
     return x1 + m, rows
 
 
-def _group_decode(cfg: ModelConfig, params, pos):
+def _group_decode(cfg: ModelConfig, params, pos, lens):
     """``step(x1, group params, group cache) -> (x1, new)``: ``new`` holds,
     for each cache leaf, the new token's K/V rows or the whole new state
-    (cross caches pass through), as :func:`write_cache` takes them."""
+    (cross caches pass through), as :func:`write_cache` takes them.  A
+    self-attention entry of the group cache may be every layer's K/V with
+    the layer's number, or for vlm the numbers of the group's self-attention
+    layers (:func:`join_kv`)."""
     fam = cfg.family
 
     if fam == "dense" and cfg.local_global:
         def step(x1, gp, gc):
             x1, cl = _dense_decode(cfg, gp["local"], x1, gc["local"], pos,
                                    window=cfg.sliding_window, ring=True)
-            x1, cg = _dense_decode(cfg, gp["global"], x1, gc["global"], pos)
+            x1, cg = _dense_decode(cfg, gp["global"], x1, gc["global"], pos,
+                                   lens)
             return x1, {"local": cl, "global": cg}
     elif fam == "dense":
         def step(x1, gp, gc):
-            x1, c = _dense_decode(cfg, gp["lyr"], x1, gc["lyr"], pos)
+            x1, c = _dense_decode(cfg, gp["lyr"], x1, gc["lyr"], pos, lens)
             return x1, {"lyr": c}
     elif fam == "moe":
         def step(x1, gp, gc):
             p = gp["lyr"]
             h = norm(cfg, p["ln1"], x1)
-            a, rows = A.attn_decode(cfg, p["attn"], h, gc["lyr"], pos)
+            a, rows = A.attn_decode(cfg, p["attn"], h, gc["lyr"], pos,
+                                    lens=lens)
             x1 = x1 + a
             h = norm(cfg, p["ln2"], x1)
             y, _ = MOE.moe_block(cfg, p["moe"], h)
             return x1 + y, {"lyr": rows}
     elif fam == "vlm":
         def step(x1, gp, gc):
+            layers, kv = split_kv(gc["self"])
+
             def body(xx, lpc):
                 lp, lc = lpc
-                return _dense_decode(cfg, lp, xx, lc, pos)
-            x1_, self_new = jax.lax.scan(body, x1, (gp["self"], gc["self"]))
+                return _dense_decode(cfg, lp, xx, join_kv(lc, kv), pos, lens)
+            x1_, self_new = jax.lax.scan(body, x1, (gp["self"], layers))
             p = gp["cross"]
             h = norm(cfg, p["ln1"], x1_)
             a = _cross_decode(cfg, p["attn"], h, gc["cross"]["k"],
@@ -247,7 +256,8 @@ def _group_decode(cfg: ModelConfig, params, pos):
                 return xx + delta, lc_new
             x1_, mamba_new = jax.lax.scan(body, x1,
                                           (gp["mamba"], gc["mamba"]))
-            x1_, attn_new = _dense_decode(cfg, shared, x1_, gc["attn"], pos)
+            x1_, attn_new = _dense_decode(cfg, shared, x1_, gc["attn"], pos,
+                                          lens)
             return x1_, {"mamba": mamba_new, "attn": attn_new}
     elif fam == "ssm":
         def step(x1, gp, gc):
@@ -258,7 +268,7 @@ def _group_decode(cfg: ModelConfig, params, pos):
             p = gp["lyr"]
             h = norm(cfg, p["ln1"], x1)
             a, rows = A.attn_decode(cfg, p["attn"], h, gc["lyr"]["self"],
-                                    pos)
+                                    pos, lens=lens)
             x1 = x1 + a
             h = norm(cfg, p["ln2"], x1)
             x1 = x1 + _cross_decode(cfg, p["cross"], h,
@@ -305,39 +315,89 @@ def write_cache(cache, new, pos):
     return jax.tree.map(functools.partial(_write_leaf, pos), cache, new)
 
 
-def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos
-               ) -> Tuple[jax.Array, Any]:
-    """tokens: (B, 1) int32; pos: scalar int32 (next write position).
+def split_kv(cache, name: str = ""):
+    """``(xs, kv)`` for a loop over a cache (or part of one): each
+    self-attention K/V entry, its leaves laid out as :data:`KV_AXES` behind
+    the loops' axes (the layer groups; for vlm also the self-attention
+    layers of a group), is replaced in ``xs`` by its layer numbers, in the
+    shape of those axes, and given in ``kv`` with those axes merged into one
+    layer axis, a free reshape.  An entry :func:`join_kv` made (every
+    layer's K/V and the numbers of a group's layers) gives back its numbers
+    and K/V, for a loop nested in the group's.  Attention then reads a
+    layer's K/V where it lies in the stacked cache: a kernel given a slice
+    of it would have XLA copy the slice out first.  Cross caches pass as
+    they are."""
+    if not isinstance(cache, dict) or name == "cross":
+        return cache, None
+    if "layer" in cache:
+        return cache["layer"], {n: t for n, t in cache.items()
+                                if n != "layer"}
+    if "k" in cache and cache["k"].ndim > len(KV_AXES):
+        loops = cache["k"].shape[:-len(KV_AXES)]
+        layers = jnp.arange(math.prod(loops), dtype=jnp.int32)
+        return layers.reshape(loops), {
+            n: t.reshape((-1,) + t.shape[len(loops):])
+            for n, t in cache.items()}
+    xs, kv = {}, {}
+    for n, t in cache.items():
+        xs[n], sub = split_kv(t, n)
+        if sub is not None:
+            kv[n] = sub
+    return xs, kv or None
+
+
+def join_kv(gc, kv):
+    """One loop step's cache from :func:`split_kv`'s ``xs`` as the loop
+    slices it: each K/V entry becomes every layer's K/V with this step's
+    layer numbers under ``'layer'`` (a scalar in the innermost loop)."""
+    if kv is None:
+        return gc
+    if "k" in kv and not isinstance(kv["k"], dict):
+        return dict(kv, layer=gc)
+    return {n: join_kv(t, kv.get(n)) for n, t in gc.items()}
+
+
+def serve_step(cfg: ModelConfig, params, cache, tokens: jax.Array, pos,
+               lens: Optional[jax.Array] = None) -> Tuple[jax.Array, Any]:
+    """tokens: (B, 1) int32; pos: scalar int32 (next write position); lens:
+    (B,) int32, the cached positions each slot attends (default ``pos`` for
+    every slot; a free slot may give 0, and its output is the caller's to
+    discard).
 
     Returns (logits (B, 1, V), updated cache).  The layer loop only reads
-    the cache; each K/V leaf gets the new token's rows of every layer in
-    one write after the loop, so with the cache donated
-    (:func:`make_serve_step`) the step updates it in place.
+    the cache, each layer's K/V where it lies (:func:`split_kv`); each K/V
+    leaf gets the new token's rows of every layer in one write after the
+    loop, so with the cache donated (:func:`make_serve_step`) the step
+    updates it in place.
     """
     x1 = embed(cfg, params, tokens)
     if cfg.family == "audio":
         table = sinusoid_pos(cache_max_len(cfg, cache), cfg.d_model)
         pe = jax.lax.dynamic_slice_in_dim(table, pos, 1)
         x1 = x1 + pe[None].astype(x1.dtype)
-    step = _group_decode(cfg, params, pos)
     blocks, stacked = params["blocks"], {}
-    if cfg.scan_layers and cfg.family == "moe" and cfg.moe_dispatch == "grouped":
-        with jax.named_scope("layers"):
+    with jax.named_scope("layers"):
+        if cfg.scan_layers and cfg.family == "moe" \
+                and cfg.moe_dispatch == "grouped":
             blocks, stacked = MOE.split_stacked(blocks)
+        cache_xs, kv = split_kv(cache)
+        if lens is None:
+            lens = jnp.full((tokens.shape[0],), pos, jnp.int32)
+    step = _group_decode(cfg, params, pos, lens)
 
     def body(carry, gpc):
         gp, gc = gpc
-        return step(carry, MOE.join_stacked(gp, stacked), gc)
+        return step(carry, MOE.join_stacked(gp, stacked), join_kv(gc, kv))
 
     with jax.named_scope("layers"):
         if cfg.scan_layers:
-            x1, new = jax.lax.scan(body, x1, (blocks, cache))
+            x1, new = jax.lax.scan(body, x1, (blocks, cache_xs))
         else:
             per_group = []
             for i in range(cfg.num_groups):
                 gp = jax.tree.map(lambda a: a[i], params["blocks"])
-                gc = jax.tree.map(lambda a: a[i], cache)
-                x1, gc_new = step(x1, gp, gc)
+                gc = jax.tree.map(lambda a: a[i], cache_xs)
+                x1, gc_new = step(x1, gp, join_kv(gc, kv))
                 per_group.append(gc_new)
             new = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
         with jax.named_scope("kv_write"):
@@ -376,8 +436,8 @@ def make_serve_step(cfg: ModelConfig):
     the one passed in is deleted once the call dispatches, so a caller
     rebinds its cache to the result.
     """
-    def step(params, cache, tokens, pos):
-        return serve_step(cfg, params, cache, tokens, pos)
+    def step(params, cache, tokens, pos, lens=None):
+        return serve_step(cfg, params, cache, tokens, pos, lens)
 
     step.__name__ = step.__qualname__ = "serve_step"
     return jax.jit(step, donate_argnums=(1,))

@@ -19,6 +19,10 @@ from repro.obs import SpanTracer, spans as SP
 SCOPE_PARTS = {part for s in decode.SERVE_SCOPES for part in s.split("/")}
 # operations that move no data and run nothing on a device
 NO_OPS = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast"}
+# the Pallas kernels of ``kernels/ops.py`` run on the CPU through their
+# interpreter, as control flow of their own; on the chip each is one custom
+# call, and counts here as one operation
+KERNELS = {"jit(decode_attention)", "jit(grouped_matmul)"}
 _COMPUTATION = re.compile(r"^(ENTRY )?%([\w.\-]+) .*\{$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*?[\s)}]([a-z][a-z0-9\-_]*)\(")
 
@@ -27,8 +31,8 @@ def executed_instructions(hlo):
     """(name, opcode, op_name or None) of each instruction of a compiled
     module that runs as an operation of its own: those of the entry
     computation and of the control-flow computations it reaches (while
-    bodies and conditions, calls, branches), not those inside fusions or
-    reducers."""
+    bodies and conditions, calls, branches), not those inside fusions,
+    reducers or a kernel's interpreter (:data:`KERNELS`)."""
     comps, entry, cur = {}, None, None
     for line in hlo.splitlines():
         m = _COMPUTATION.match(line)
@@ -45,11 +49,12 @@ def executed_instructions(hlo):
         seen.add(comp)
         for line in comps[comp]:
             name, op = _INSTRUCTION.match(line).groups()
-            if op in ("while", "call", "conditional"):
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            kernel = op_name and KERNELS & set(op_name.group(1).split("/"))
+            if op in ("while", "call", "conditional") and not kernel:
                 todo += re.findall(r"(?:condition|body|to_apply)=%([\w.\-]+)", line)
                 for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
                     todo += [c.strip().lstrip("%") for c in group.split(",")]
-            op_name = re.search(r'op_name="([^"]*)"', line)
             out.append((name, op, op_name.group(1) if op_name else None))
     return out
 

@@ -99,10 +99,11 @@ def _nbytes(tree):
     return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
 
 
-def _compile_qwen2_step(sharding, slots, max_len):
-    """(compiled step, param specs, cache specs) of the full-width qwen2-0.5b
-    serve step for ``slots`` x ``max_len``."""
-    cfg = get_config("qwen2-0.5b")
+def _compile_step(sharding, cfg, slots, max_len):
+    """(compiled step, param specs, cache specs) of ``cfg``'s serve step for
+    ``slots`` x ``max_len``, called as the engine calls it (tokens, position
+    and each slot's length), with the Pallas kernels compiled for the
+    chip."""
     params = MP.param_specs(cfg)
     cache = jax.eval_shape(
         functools.partial(decode.init_cache, cfg, batch=slots,
@@ -112,10 +113,32 @@ def _compile_qwen2_step(sharding, slots, max_len):
         return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype),
                             tree)
 
-    compiled = decode.make_serve_step(cfg).lower(
-        place(params), place(cache), _spec(sharding, (slots, 1), jnp.int32),
-        _spec(sharding, (), jnp.int32)).compile()
+    interpret = ops._interpret
+    ops._interpret = lambda: False
+    try:
+        compiled = decode.make_serve_step(cfg).lower(
+            place(params), place(cache),
+            _spec(sharding, (slots, 1), jnp.int32),
+            _spec(sharding, (), jnp.int32),
+            _spec(sharding, (slots,), jnp.int32)).compile()
+    finally:
+        ops._interpret = interpret
     return compiled, params, cache
+
+
+def _compile_qwen2_step(sharding, slots, max_len):
+    """(compiled step, param specs, cache specs) of the full-width qwen2-0.5b
+    serve step for ``slots`` x ``max_len``."""
+    return _compile_step(sharding, get_config("qwen2-0.5b"), slots, max_len)
+
+
+def _olmoe_0924():
+    """OLMoE-1B-7B-0924 as its benchmark cell serves it: 8 layers at the
+    published widths, QK-norm, plain gates, grouped experts."""
+    return dataclasses.replace(get_config("olmoe-1b-7b"), num_layers=8,
+                               norm_eps=1e-5, qk_norm=True,
+                               moe_norm_topk_prob=False,
+                               moe_dispatch="grouped")
 
 
 def test_olmoe_0924_serve_step_fits_one_chip(one_chip, monkeypatch):
@@ -123,9 +146,7 @@ def test_olmoe_0924_serve_step_fits_one_chip(one_chip, monkeypatch):
     gates, grouped experts) for 64 x 1024: weights and donated cache on one
     chip, the expert matmuls as kernels reading the stacked weights."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), num_layers=8, norm_eps=1e-5,
-                              qk_norm=True, moe_norm_topk_prob=False,
-                              moe_dispatch="grouped")
+    cfg = _olmoe_0924()
     params = MP.param_specs(cfg)
     cache = jax.eval_shape(functools.partial(decode.init_cache, cfg, batch=64,
                                              max_len=1024), params)
@@ -133,7 +154,7 @@ def test_olmoe_0924_serve_step_fits_one_chip(one_chip, monkeypatch):
     compiled = decode.make_serve_step(cfg).lower(
         place(params), place(cache), _spec(one_chip, (64, 1), jnp.int32),
         _spec(one_chip, (), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") >= 4
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _nbytes(cache)
     # the kernels read each layer's experts out of the stacked weights: no
@@ -231,3 +252,52 @@ def test_qwen2_serve_step_updates_the_cache_in_place(one_chip, slots,
     row = _size(stacked) // max_len
     assert len(whole) == 2 and all(n == row for _, n in whole), whole
     assert in_loop == []
+
+
+# the benchmark cells (configuration, slots x max_len), and llama-3.2-vision
+# (two groups of four self-attention layers and a cross-attention layer) at
+# a small batch: its self-attention cache stacks a group's layers inside
+# the group axis
+CELLS = {"qwen2-0.5b.batch_prefill": ("qwen2-0.5b", 512, 528),
+         "qwen2-0.5b.batch_decode": ("qwen2-0.5b", 256, 1024),
+         "olmoe-1b-7b-0924.chat_decode": ("olmoe-1b-7b-0924", 64, 1024),
+         "llama-3.2-vision-11b.8x1024": ("llama-3.2-vision-11b", 8, 1024)}
+
+
+def _cell_config(name):
+    if name == "olmoe-1b-7b-0924":
+        return _olmoe_0924()
+    if name == "llama-3.2-vision-11b":
+        return dataclasses.replace(get_config(name), num_layers=10)
+    return get_config(name)
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_decode_attention_kernel_reads_the_stacked_cache(one_chip, cell):
+    """At each cell's shape the step's attention is the decode-attention
+    kernel, a custom call named under ``attn`` (so ``attn_ms_per_step``
+    reads it), taking every layer's cache: the cache is still aliased to the
+    output, and no layer's K or V is copied out of it.  The temporaries grow
+    by less than half a layer's K when the cache doubles in length, and at
+    the benchmark cells' shapes are the f32 logits and less than half a
+    layer's K (llama-3.2-vision's step copies a group's self-attention
+    weights out of their stack, the size of no cache)."""
+    name, slots, max_len = CELLS[cell]
+    cfg = _cell_config(name)
+    compiled, _, cache = _compile_step(one_chip, cfg, slots, max_len)
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and "op_name=" in line]
+    attn = [c for c in calls if "/attn/" in c]
+    assert len(attn) == 1 and "decode_attention" in attn[0], calls
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    layer_k = (slots * max_len * cfg.num_kv_heads * cfg.head_dim
+               * jnp.dtype(cfg.dtype).itemsize)
+    longer = _compile_step(one_chip, cfg, slots, 2 * max_len)[0]
+    grown = longer.memory_analysis().temp_size_in_bytes - mem.temp_size_in_bytes
+    assert grown < layer_k / 2, (grown, layer_k)
+    if name != "llama-3.2-vision-11b":
+        logits = slots * cfg.vocab_size * 4
+        assert mem.temp_size_in_bytes < logits + layer_k / 2, \
+            mem.temp_size_in_bytes
