@@ -1,17 +1,9 @@
-"""Roofline / offload-candidate report (§Roofline source of truth).
+"""Roofline report of the dry-run cells.
 
-Primary source: the committed ``BENCH_model.json`` — measured per-operator
-decode-step profiles (``benchmarks/model_profile_bench.py``) joined with
-the analytic cost model at the deployment shape and roofline-classed
-against the device peaks.  One row per (arch, operator) ranked by
-measured share of step time: the Calyx-lowering work order.
-
-Optional enrichment: if dry-run artifacts exist
-(``python -m repro.launch.dryrun --all --both``), the whole-model
-roofline cells (compute/memory/collective seconds, dominant resource)
-are emitted alongside.  Their absence is not an error — the committed
-profile is the source of truth; the dry-run sweep is a deeper cut over
-shapes and meshes.
+Reads the artifacts of a dry-run sweep
+(``python -m repro.launch.dryrun --all --both``) and emits one row per
+cell: its compute/memory/collective seconds and its dominant resource.
+With no artifacts it emits nothing.
 """
 from __future__ import annotations
 
@@ -19,12 +11,7 @@ import json
 import pathlib
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-BENCH_MODEL = ROOT / "BENCH_model.json"
 ARTIFACTS = ROOT / "artifacts"
-
-
-def load_model_bench(path: pathlib.Path = BENCH_MODEL):
-    return json.loads(path.read_text())
 
 
 def load_cells(dirname: str):
@@ -38,26 +25,6 @@ def load_cells(dirname: str):
 
 
 def run(emit) -> None:
-    # -- primary: committed per-operator profiles -------------------------
-    bench = load_model_bench()
-    for rec in bench["records"]:
-        arch = rec["arch"]
-        shape = rec["full_shape"]
-        for row in rec["offload"]:
-            emit(f"roofline_{arch}_{row['op']}", row["wall_us_mean"],
-                 f"rank={row['rank']}"
-                 f"|share={row['share']:.0%}"
-                 f"|flops={row['flops_per_step']:.3e}"
-                 f"|bytes={row['bytes_per_step']:.3e}"
-                 f"|intensity={row['intensity']:.1f}"
-                 f"|bound={row['bound']}"
-                 f"@B{shape['batch']}xS{shape['cache_len']}")
-        top = rec["offload"][0]
-        emit(f"roofline_{arch}_offload_top", 0.0,
-             f"{top['op']} ({top['share']:.0%} of step, {top['bound']}"
-             f"-bound) -> first Calyx lowering candidate")
-
-    # -- enrichment: dry-run sweep cells when present ---------------------
     for label, dirname in (("base", "dryrun_baseline"),
                            ("opt", "dryrun_opt")):
         for r in load_cells(dirname):

@@ -7,8 +7,7 @@ Prints ``name,us_per_call,derived`` CSV per line.  Sections:
   serve_bench       serving load harness -> BENCH_serve.json
   resilience_bench  chaos/goodput harness -> BENCH_resilience.json
   kernel_bench      Pallas kernel microbenches (interpret mode)
-  model_profile_bench  per-operator decode profiles -> BENCH_model.json
-  roofline_report   offload ranking from BENCH_model.json (+ dry-run cells)
+  roofline_report   roofline rows of the dry-run cells, when present
 """
 from __future__ import annotations
 
@@ -25,7 +24,7 @@ def main() -> None:
     sections = sys.argv[1:] or ["paper_tables", "banking_ablation",
                                 "calyx_bench", "serve_bench",
                                 "resilience_bench", "kernel_bench",
-                                "model_profile_bench", "roofline_report"]
+                                "roofline_report"]
     t0 = time.time()
     failures = []
     for section in sections:
@@ -49,9 +48,6 @@ def main() -> None:
             elif section == "kernel_bench":
                 from benchmarks import kernel_bench
                 kernel_bench.run(_emit)
-            elif section == "model_profile_bench":
-                from benchmarks import model_profile_bench
-                model_profile_bench.run(_emit)
             elif section == "roofline_report":
                 from benchmarks import roofline_report
                 roofline_report.run(_emit)

@@ -28,8 +28,7 @@ from repro.launch import faults as FLT, resilience as RES
 from repro.launch.serve import serve_metrics
 from repro.models import decode, get_config
 from repro.models import params as MP
-from repro.obs import MetricsRegistry, SpanTracer, modelprof as MPF, \
-    spans as SP
+from repro.obs import MetricsRegistry, SpanTracer, spans as SP
 
 
 def main():
@@ -44,13 +43,9 @@ def main():
                          "(.json -> JSON, anything else -> Prometheus text)")
     ap.add_argument("--spans-out", default="",
                     help="write the span event stream here as JSONL")
-    ap.add_argument("--profile-layers", default="",
-                    help="run the sliced per-operator decode step and "
-                         "write one layer record per (op, step) here as "
-                         "JSONL (repro.obs.modelprof schema)")
     ap.add_argument("--stable", action="store_true",
-                    help="normalize wall-clock fields in the span and "
-                         "layer exports")
+                    help="normalize wall-clock fields in the span "
+                         "export")
     ap.add_argument("--fault-plan", default="",
                     help="replay a FaultPlan JSON (repro.launch.faults): "
                          "nan/inf logits, latency spikes, and cache "
@@ -67,9 +62,6 @@ def main():
 
     plan = None
     if args.fault_plan:
-        if args.profile_layers:
-            ap.error("--fault-plan and --profile-layers are mutually "
-                     "exclusive (fault replay targets the standard path)")
         plan = FLT.FaultPlan.load(args.fault_plan)
     resilient = plan is not None or args.deadline_ms > 0
 
@@ -86,41 +78,18 @@ def main():
         modality = jnp.asarray(rng.normal(
             size=(args.requests, cfg.encoder_seq, cfg.d_model)), cfg.dtype)
 
-    layers = None
-    if args.profile_layers:
-        if cfg.family not in decode.PROFILED_FAMILIES:
-            ap.error(f"--profile-layers supports families "
-                     f"{decode.PROFILED_FAMILIES}, not {cfg.family}")
-        layers = MPF.LayerProfiler()
-
-    if layers is not None:
-        pstep = decode.make_profiled_serve_step(cfg)
-        cache = decode.ProfiledServeStep.init_cache(cfg, params,
-                                                    args.requests, max_len)
-    else:
-        cache = decode.init_cache(cfg, params, args.requests, max_len,
-                                  modality=modality)
-        step = decode.make_serve_step(cfg)
+    cache = decode.init_cache(cfg, params, args.requests, max_len,
+                              modality=modality)
+    step = decode.make_serve_step(cfg)
 
     metrics = MetricsRegistry() if args.metrics_out else None
     spans_tr = SpanTracer() if args.spans_out else None
     observing = metrics is not None or spans_tr is not None
-    m = serve_metrics(metrics, cfg, args.requests,
-                      decode.ProfiledServeStep.stack_cache(cache)
-                      if layers is not None else cache) \
+    m = serve_metrics(metrics, cfg, args.requests, cache) \
         if metrics is not None else None
     now_us = spans_tr.now_us if spans_tr is not None \
         else lambda t0=time.perf_counter(): int((time.perf_counter() - t0)
                                                 * 1e6)
-
-    if layers is not None:
-        def step(params, cache, toks, pos):
-            """Sliced step: record one layer wall per operator, stamped on
-            the span tracer's clock when one is attached (one-clock rule)."""
-            logits, cache, walls = pstep(params, cache, toks, pos)
-            layers.on_step(int(pos), pstep.ops, walls,
-                           ts_us=now_us() if spans_tr is not None else None)
-            return logits, cache
 
     prompts = rng.integers(1, cfg.vocab_size,
                            size=(args.requests, args.prompt_len)).astype(
@@ -350,17 +319,6 @@ def main():
                                 epoch_ns=spans_tr.epoch_ns))
         print(f"{len(spans_tr.events)} span events -> {args.spans_out}"
               f"{' (stable)' if args.stable else ''}")
-    if layers is not None:
-        problems = MPF.validate(layers.records, cfg=cfg,
-                                engine_steps=steps_run)
-        if spans_tr is not None:
-            problems += MPF.join_mismatches(layers.records, spans_tr.events,
-                                            cfg=cfg)
-        assert not problems, problems
-        with open(args.profile_layers, "w") as f:
-            f.write(MPF.to_jsonl(layers.records, stable=args.stable))
-        print(f"{len(layers.records)} layer records -> "
-              f"{args.profile_layers}{' (stable)' if args.stable else ''}")
     finite = np.isfinite(np.asarray(logits, np.float32))
     assert finite[alive].all() if resilient else finite.all()
     print("OK")

@@ -7,12 +7,6 @@ Trains the reduced config on the synthetic bigram corpus with the full
 runtime: AdamW + schedule, periodic checkpoints, restart-on-failure, and
 straggler detection.  Loss must drop well below ln(vocab) as the model
 learns the planted bigrams.
-
-``--profile-layers PATH`` additionally runs a short greedy decode of the
-*trained* parameters through the sliced per-operator step and writes the
-layer-record JSONL (``repro.obs.modelprof`` schema) — the same artifact
-the serving drivers emit, so a training run can hand its checkpoint's
-operator profile straight to the offload analysis.
 """
 import argparse
 import tempfile
@@ -35,14 +29,6 @@ def main():
     ap.add_argument("--inject-failure", type=int, default=-1,
                     help="simulate a node failure at this step")
     ap.add_argument("--ckpt-dir", default=None)
-    ap.add_argument("--profile-layers", default="",
-                    help="after training, profile a short greedy decode "
-                         "of the trained params and write the per-operator "
-                         "layer records here as JSONL")
-    ap.add_argument("--profile-steps", type=int, default=8,
-                    help="decode steps for --profile-layers")
-    ap.add_argument("--stable", action="store_true",
-                    help="normalize wall-clock fields in the layer export")
     args = ap.parse_args()
 
     cfg = get_config(args.arch).reduced()
@@ -75,31 +61,6 @@ def main():
         print("stragglers:", tr.detector.stragglers())
     assert losses[-1] < losses[0], "loss did not decrease"
 
-    if args.profile_layers:
-        import jax.numpy as jnp
-        from repro.models import decode
-        from repro.obs import modelprof as MPF
-        if cfg.family not in decode.PROFILED_FAMILIES:
-            ap.error(f"--profile-layers supports families "
-                     f"{decode.PROFILED_FAMILIES}, not {cfg.family}")
-        n, batch = args.profile_steps, 2
-        pstep = decode.make_profiled_serve_step(cfg)
-        cache = decode.ProfiledServeStep.init_cache(
-            cfg, state["params"], batch, n + 1)
-        layers = MPF.LayerProfiler()
-        tok = jnp.ones((batch, 1), jnp.int32)
-        for i in range(n):
-            logits, cache, walls = pstep(state["params"], cache, tok,
-                                         jnp.asarray(i, jnp.int32))
-            layers.on_step(i, pstep.ops, walls)
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(
-                jnp.int32)[:, None]
-        problems = MPF.validate(layers.records, cfg=cfg, engine_steps=n)
-        assert not problems, problems
-        with open(args.profile_layers, "w") as f:
-            f.write(MPF.to_jsonl(layers.records, stable=args.stable))
-        print(f"{len(layers.records)} layer records -> "
-              f"{args.profile_layers}{' (stable)' if args.stable else ''}")
     print("OK")
 
 

@@ -39,34 +39,11 @@ magnitude breakage, not percent drift).  Independently of the baseline,
 every new serve point's ``trace_overhead`` (tracing-off vs tracing-on
 per-tick wall, measured in lockstep by the bench) must stay under
 ``--serve-trace-overhead`` (default 5%), every point must be
-``deterministic`` and every request must have completed.  Either gate
-(calyx, serve) may be run alone by passing only its file pair.
+``deterministic`` and every request must have completed.  Each gate
+(calyx, serve, resilience) may be run alone by passing only its file pair.
 
     PYTHONPATH=src python scripts/check_perf_regression.py \
         --serve-baseline BENCH_serve.json --serve-new /tmp/serve_new.json
-
-``BENCH_model.json`` (the per-operator decode profiles,
-``benchmarks/model_profile_bench.py``) is gated via
-``--model-baseline``/``--model-new``: points are matched on arch.  Three
-checks per new point, mirroring the bench's own contracts:
-
-* ``record_overhead`` (recording vs record-off sliced engines, measured
-  in lockstep by the bench) must stay under ``--model-overhead``
-  (default 5%) — exact, like the serve trace-overhead gate;
-* the analytic-vs-HLO cross-check must hold exactly as committed:
-  ``flops_rel_err`` within ``--model-flops-rtol`` and ``bytes_ratio``
-  inside the ``--model-bytes-factor`` band (defaults match
-  ``repro.obs.modelprof``'s calibrated constants — this is a determinism
-  check on the cost model, not a wall clock, so there is no noise
-  allowance);
-* per-operator mean walls against the baseline at
-  ``--model-tolerance`` (default 3.0 = 4x — cross-machine microsecond
-  walls of sub-millisecond segments; catches an operator suddenly
-  dominating, not percent drift).  The stream must also be
-  ``deterministic`` and the join coverage p50 positive.
-
-    PYTHONPATH=src python scripts/check_perf_regression.py \
-        --model-baseline BENCH_model.json --model-new /tmp/model_new.json
 
 ``BENCH_resilience.json`` (the chaos/goodput harness,
 ``benchmarks/resilience_bench.py``) is gated via
@@ -166,63 +143,6 @@ def check_serve(args) -> Tuple[list, list]:
     return regressions, contract
 
 
-def load_model(path: str) -> Dict[str, dict]:
-    with open(path) as f:
-        data = json.load(f)
-    return {rec["arch"]: rec for rec in data.get("records", [])}
-
-
-def check_model(args) -> Tuple[list, list]:
-    """Returns (regressions, contract_failures) over the model files."""
-    base = load_model(args.model_baseline) if args.model_baseline else {}
-    new = load_model(args.model_new)
-    regressions = []
-    contract = []
-    for arch, rec in sorted(new.items()):
-        ovh = float(rec.get("record_overhead", 0.0))
-        tag = "ok" if ovh < args.model_overhead else "FAIL"
-        print(f"  model {arch}: record_overhead={ovh:+.1%} "
-              f"(limit {args.model_overhead:.0%}) {tag}")
-        if ovh >= args.model_overhead:
-            contract.append(f"{arch}: record overhead {ovh:+.1%}")
-        if not rec.get("deterministic", False):
-            contract.append(f"{arch}: layer stream not deterministic")
-        cc = rec.get("crosscheck", {})
-        rel = float(cc.get("flops_rel_err", 0.0))
-        ratio = float(cc.get("bytes_ratio", 1.0))
-        ok_cc = (rel <= args.model_flops_rtol
-                 and 1.0 / args.model_bytes_factor <= ratio
-                 <= args.model_bytes_factor)
-        print(f"  model {arch}: flops_rel_err={rel:.4f} "
-              f"bytes_ratio={ratio:.2f} {'ok' if ok_cc else 'FAIL'}")
-        if not ok_cc:
-            contract.append(f"{arch}: analytic/HLO cross-check broken "
-                            f"(rel_err={rel:.4f}, ratio={ratio:.2f})")
-        cov = rec.get("coverage", {}).get("p50", 0.0)
-        if cov <= 0:
-            contract.append(f"{arch}: join coverage p50 {cov}")
-        if arch not in base:
-            if base:
-                print(f"  model {arch}: new point (no baseline)")
-            continue
-        ref_walls = {r["op"]: float(r["wall_us_mean"])
-                     for r in base[arch].get("offload", [])}
-        for row in rec.get("offload", []):
-            op, new_v = row["op"], float(row["wall_us_mean"])
-            ref_v = ref_walls.get(op)
-            if ref_v is None or ref_v <= 0:
-                continue
-            delta = (new_v - ref_v) / ref_v
-            bad = new_v > ref_v * (1.0 + args.model_tolerance)
-            print(f"  model {arch}.{op}: wall {ref_v:.1f} -> {new_v:.1f}us "
-                  f"({delta:+.1%}) {'REGRESSION' if bad else 'ok'}")
-            if bad:
-                regressions.append(
-                    f"{arch}.{op}: wall {delta:+.1%} beyond "
-                    f"{args.model_tolerance:.0%} tolerance")
-    return regressions, contract
-
-
 def load_resilience(path: str) -> Dict[tuple, dict]:
     with open(path) as f:
         data = json.load(f)
@@ -307,23 +227,6 @@ def main() -> int:
     ap.add_argument("--serve-trace-overhead", type=float, default=0.05,
                     help="max per-point tracing overhead in the new serve "
                          "file (default 5%%)")
-    ap.add_argument("--model-baseline",
-                    help="committed BENCH_model.json")
-    ap.add_argument("--model-new",
-                    help="freshly generated model profile JSON")
-    ap.add_argument("--model-overhead", type=float, default=0.05,
-                    help="max per-point layer-record overhead in the new "
-                         "model file (default 5%%)")
-    ap.add_argument("--model-tolerance", type=float, default=3.0,
-                    help="allowed relative per-operator wall growth vs the "
-                         "model baseline (default 3.0 = 4x; microsecond "
-                         "segment walls are cross-machine noisy)")
-    ap.add_argument("--model-flops-rtol", type=float, default=0.02,
-                    help="max analytic-vs-HLO flops relative error "
-                         "(matches repro.obs.modelprof.FLOPS_RTOL)")
-    ap.add_argument("--model-bytes-factor", type=float, default=5.0,
-                    help="analytic-vs-HLO bytes ratio band (matches "
-                         "repro.obs.modelprof.BYTES_FACTOR)")
     ap.add_argument("--resilience-baseline",
                     help="committed BENCH_resilience.json")
     ap.add_argument("--resilience-new",
@@ -339,13 +242,10 @@ def main() -> int:
         ap.error("--baseline and --new must be given together")
     if args.serve_baseline and not args.serve_new:
         ap.error("--serve-baseline requires --serve-new")
-    if args.model_baseline and not args.model_new:
-        ap.error("--model-baseline requires --model-new")
     if args.resilience_baseline and not args.resilience_new:
         ap.error("--resilience-baseline requires --resilience-new")
-    if not args.new and not args.serve_new and not args.model_new \
-            and not args.resilience_new:
-        ap.error("give --baseline/--new, --serve-new, --model-new and/or "
+    if not args.new and not args.serve_new and not args.resilience_new:
+        ap.error("give --baseline/--new, --serve-new and/or "
                  "--resilience-new")
 
     regressions = []
@@ -403,9 +303,6 @@ def main() -> int:
     serve_regressions, serve_contract = ([], [])
     if args.serve_new:
         serve_regressions, serve_contract = check_serve(args)
-    model_regressions, model_contract = ([], [])
-    if args.model_new:
-        model_regressions, model_contract = check_model(args)
     res_regressions, res_contract = ([], [])
     if args.resilience_new:
         res_regressions, res_contract = check_resilience(args)
@@ -427,10 +324,6 @@ def main() -> int:
     if serve_regressions or serve_contract:
         for msg in serve_regressions + serve_contract:
             print(f"\nFAIL: serve {msg}")
-        return 1
-    if model_regressions or model_contract:
-        for msg in model_regressions + model_contract:
-            print(f"\nFAIL: model {msg}")
         return 1
     if res_regressions or res_contract:
         for msg in res_regressions + res_contract:

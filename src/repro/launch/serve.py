@@ -63,8 +63,6 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.models import decode, get_config
 from repro.models import params as MP
 from repro.obs import MetricsRegistry, SpanTracer, spans as SP, traffic
-from repro.obs.modelprof import LayerProfiler
-from repro.obs import modelprof as MPF
 
 
 class Request:
@@ -174,7 +172,6 @@ class Engine:
     def __init__(self, cfg, params, slots: int, max_len: int,
                  metrics: Optional[MetricsRegistry] = None,
                  spans: Optional[SpanTracer] = None,
-                 layers: Optional["LayerProfiler"] = None,
                  faults: Optional[FLT.FaultPlan] = None,
                  resilience: Optional[RES.ResilienceConfig] = None):
         self.cfg = cfg
@@ -182,13 +179,6 @@ class Engine:
         self.slots: List[Optional[Request]] = [None] * slots
         self.pos = 0
         self.max_len = max_len
-        # attaching a layer profiler switches the engine to the sliced
-        # per-operator step (same math, bit-identical logits — asserted by
-        # tests) whose cache travels in per-group list form; the fused
-        # engine pays nothing for the feature existing
-        self.layers = layers
-        self._prof = decode.make_profiled_serve_step(cfg) \
-            if layers is not None else None
         self.cache = self._new_cache()
         # the cache is donated to the step, which takes over its buffers
         self._step = decode.make_serve_step(cfg)
@@ -235,9 +225,6 @@ class Engine:
 
     def _new_cache(self):
         with TraceAnnotation("serve.init_cache"):
-            if self._prof is not None:
-                return decode.ProfiledServeStep.init_cache(
-                    self.cfg, self.params, len(self.slots), self.max_len)
             return decode.init_cache(self.cfg, self.params, len(self.slots),
                                      self.max_len)
 
@@ -588,19 +575,14 @@ class Engine:
                 (toks, np.array(self.pos, np.int32), lens))
             if self._m is not None:
                 self._m["kv_live"].inc(int(lens.sum()))
-        seg_walls: Optional[List[float]] = None
         before = self.cache
         # a device fault shows where the step is called or, on an
         # accelerator, only where its results are read: dispatch, sample
         # and sync fail as one
         try:
             with TraceAnnotation("serve.dispatch"):
-                if self._prof is not None:
-                    logits, self.cache, seg_walls = self._prof(
-                        self.params, self.cache, toks_d, pos_d)
-                else:
-                    logits, self.cache = self._step(
-                        self.params, self.cache, toks_d, pos_d, lens_d)
+                logits, self.cache = self._step(
+                    self.params, self.cache, toks_d, pos_d, lens_d)
             with TraceAnnotation("serve.sample"):
                 for f in pending:
                     if f.kind in (FLT.NAN_LOGITS, FLT.INF_LOGITS):
@@ -694,13 +676,6 @@ class Engine:
                 m["pre"].inc(prefill_fed)
                 m["occ"].set(self.inflight)
                 m["step_h"].observe(wall_us)
-            if self.layers is not None and seg_walls is not None:
-                # one-clock rule: when a span tracer is attached its epoch
-                # is authoritative, so the layer records stamp with the
-                # same post-step `now` as the step span they join to
-                self.layers.on_step(
-                    self.steps, self._prof.ops, seg_walls,
-                    ts_us=now if self.spans is not None else None)
             self._health_step(detected=bool(bad))
             self._tick += 1 + spike_ticks
             self._enforce_deadlines()
@@ -884,13 +859,9 @@ def main():
                          "(.json -> JSON, anything else -> Prometheus text)")
     ap.add_argument("--spans-out", default="",
                     help="write the span event stream here as JSONL")
-    ap.add_argument("--profile-layers", default="",
-                    help="run the sliced per-operator step and write one "
-                         "layer record per operator per engine step here "
-                         "as JSONL (repro.obs.modelprof)")
     ap.add_argument("--stable", action="store_true",
-                    help="normalize wall-clock fields in the span/layer "
-                         "exports (byte-identical across same-seed runs)")
+                    help="normalize wall-clock fields in the span "
+                         "export (byte-identical across same-seed runs)")
     ap.add_argument("--fault-plan", default="",
                     help="replay a FaultPlan JSON (repro.launch.faults); "
                          "auto-enables resilience")
@@ -940,9 +911,8 @@ def main():
 
     metrics = MetricsRegistry() if args.metrics_out else None
     spans_tr = SpanTracer() if args.spans_out else None
-    layers = LayerProfiler() if args.profile_layers else None
     eng = Engine(cfg, params, args.slots, max_len,
-                 metrics=metrics, spans=spans_tr, layers=layers,
+                 metrics=metrics, spans=spans_tr,
                  faults=plan, resilience=res)
 
     t0 = time.perf_counter()
@@ -984,17 +954,6 @@ def main():
                                 epoch_ns=spans_tr.epoch_ns))
         print(f"[serve] {len(spans_tr.events)} span events -> "
               f"{args.spans_out}{' (stable)' if args.stable else ''}")
-    if layers is not None:
-        problems = MPF.validate(layers.records, cfg=cfg,
-                                engine_steps=eng.steps)
-        if spans_tr is not None:
-            problems += MPF.join_mismatches(layers.records,
-                                            spans_tr.events, cfg=cfg)
-        assert not problems, problems
-        with open(args.profile_layers, "w") as f:
-            f.write(MPF.to_jsonl(layers.records, stable=args.stable))
-        print(f"[serve] {len(layers.records)} layer records -> "
-              f"{args.profile_layers}{' (stable)' if args.stable else ''}")
     assert len(eng.done) == args.requests, "requests lost by the engine"
     if plan is None and res is None:
         assert len(finished) == args.requests, "not all requests completed"

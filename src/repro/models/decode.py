@@ -24,10 +24,9 @@ from . import attention as A
 from . import mamba as M
 from . import moe as MOE
 from . import rwkv as R
-from .common import embed, lm_logits, norm, rope_freqs, sinusoid_pos
+from .common import embed, lm_logits, norm, sinusoid_pos
 from .config import ModelConfig
 from .mlp import mlp_block
-from .params import param_specs
 from .transformer import encode_audio
 
 
@@ -486,14 +485,8 @@ def cache_batch_axes(cfg: ModelConfig):
 
 
 def _map_slot(cfg: ModelConfig, cache, fn):
-    """Apply ``fn(leaf, batch_axis)`` across a fused cache tree or the
-    per-group list form (``ProfiledServeStep``), where the sliced-off
-    group axis shifts every batch axis down by one."""
-    axes = cache_batch_axes(cfg)
-    if isinstance(cache, list):
-        return [jax.tree.map(lambda leaf, ax: fn(leaf, ax - 1), g, axes)
-                for g in cache]
-    return jax.tree.map(fn, cache, axes)
+    """Apply ``fn(leaf, batch_axis)`` across every leaf of the cache."""
+    return jax.tree.map(fn, cache, cache_batch_axes(cfg))
 
 
 def reset_cache_slot(cfg: ModelConfig, cache, slot: int):
@@ -517,237 +510,3 @@ def corrupt_cache_slot(cfg: ModelConfig, cache, slot: int):
         idx = (slice(None),) * ax + (slot,)
         return leaf.at[idx].set(jnp.nan)
     return _map_slot(cfg, cache, poison)
-
-
-# ---------------------------------------------------------------------------
-# Per-operator sliced serve step (layer profiling — ``repro.obs.modelprof``)
-# ---------------------------------------------------------------------------
-
-# families with a sliced-segment decomposition; vlm/audio decode steps fold
-# modality cross-attention into the group scan and are not sliced yet
-PROFILED_FAMILIES = ("dense", "moe", "ssm", "hybrid")
-
-
-def profile_ops(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
-    """Ordered ``(op, group)`` decomposition of one serve_step.
-
-    ``group`` is the scan-group index (``-1`` for the embed/head segments
-    outside the block stack).  This is the canonical op list the layer
-    profiler, its validator, and the analytic cost model all share — one
-    record per entry per engine step.
-    """
-    if cfg.family not in PROFILED_FAMILIES:
-        raise NotImplementedError(
-            f"layer profiling not implemented for family {cfg.family!r} "
-            f"(supported: {PROFILED_FAMILIES})")
-    ops = [("embed", -1)]
-    for g in range(cfg.num_groups):
-        if cfg.family == "dense" and cfg.local_global:
-            ops += [("attn_local", g), ("mlp_local", g),
-                    ("attn_global", g), ("mlp_global", g)]
-        elif cfg.family == "dense":
-            ops += [("attn", g), ("mlp", g)]
-        elif cfg.family == "moe":
-            ops += [("attn", g), ("moe", g)]
-        elif cfg.family == "ssm":
-            ops += [("time_mix", g), ("channel_mix", g)]
-        else:  # hybrid
-            ops += [("scan", g), ("attn", g), ("mlp", g)]
-    ops.append(("head", -1))
-    return tuple(ops)
-
-
-class ProfiledServeStep:
-    """One decode step as a sequence of independently jitted segments
-    (embed / per-group operators / head), each synced with
-    ``jax.block_until_ready`` and wall-stamped.
-
-    This is a distinct *execution mode* of the identical math as
-    :func:`serve_step` (logits/cache agree with the fused step — asserted
-    by tests): slicing the step loses XLA's cross-operator fusion and pays
-    one dispatch+sync per segment, so a profiled engine is slower than a
-    fused one by a measured, reported factor (``slice_overhead`` in
-    BENCH_model.json).  The <5% observability contract covers the
-    *recording* layer on top of this mode (see ``obs.modelprof``), exactly
-    as PR 8's contract covered the span hooks on top of the engine's
-    inherent per-step sync.
-
-    The cache travels as a **list of per-group subtrees** (no per-step
-    slice/stack device work — group slicing of the parameters happens once
-    per params object and is memoized).  ``init_cache``/``stack_cache``
-    convert to and from the fused layout.
-    """
-
-    def __init__(self, cfg: ModelConfig):
-        self.cfg = cfg
-        self.ops = profile_ops(cfg)
-        self._gps = None
-        self._params_id = None
-        self._aux = None            # head/embed/shared params, sliced once
-        self._segs = self._build_segments(cfg)
-
-    # -- cache layout --------------------------------------------------------
-
-    @staticmethod
-    def init_cache(cfg: ModelConfig, params, batch: int, max_len: int):
-        """Family cache in per-group list form."""
-        c = init_cache(cfg, params, batch, max_len)
-        return [jax.tree.map(lambda a: a[g], c)
-                for g in range(cfg.num_groups)]
-
-    @staticmethod
-    def stack_cache(groups):
-        """Per-group list form back to the fused (stacked) layout."""
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
-
-    # -- segment builders ----------------------------------------------------
-
-    def _build_segments(self, cfg: ModelConfig):
-        fam = cfg.family
-        segs: Dict[str, Any] = {}
-
-        def embed_seg(emb, tokens):
-            return embed(cfg, {"embed": emb}, tokens)
-
-        def head_seg(final_norm, head_w, x1):
-            x1 = norm(cfg, final_norm, x1)
-            params = {"embed" if cfg.tie_embeddings else "lm_head": head_w}
-            return lm_logits(cfg, params, x1)
-
-        segs["embed"] = jax.jit(embed_seg)
-        segs["head"] = jax.jit(head_seg)
-
-        def dense_attn(p, x1, c, pos, window=0, ring=False):
-            h = norm(cfg, p["ln1"], x1)
-            a, rows = A.attn_decode(cfg, p["attn"], h, c, pos,
-                                    window=window,
-                                    attn_softcap=cfg.attn_softcap,
-                                    ring=ring)
-            if "ln1_post" in p:
-                a = norm(cfg, p["ln1_post"], a)
-            return x1 + a, write_cache(c, rows, pos)
-
-        def dense_mlp(p, x1):
-            h = norm(cfg, p["ln2"], x1)
-            m = mlp_block(cfg, p["mlp"], h)
-            if "ln2_post" in p:
-                m = norm(cfg, p["ln2_post"], m)
-            return x1 + m
-
-        if fam == "dense" and cfg.local_global:
-            segs["attn_local"] = jax.jit(functools.partial(
-                dense_attn, window=cfg.sliding_window, ring=True))
-            segs["mlp_local"] = jax.jit(dense_mlp)
-            segs["attn_global"] = jax.jit(dense_attn)
-            segs["mlp_global"] = jax.jit(dense_mlp)
-        elif fam == "dense":
-            segs["attn"] = jax.jit(dense_attn)
-            segs["mlp"] = jax.jit(dense_mlp)
-        elif fam == "moe":
-            def moe_attn(p, x1, c, pos):
-                h = norm(cfg, p["ln1"], x1)
-                a, rows = A.attn_decode(cfg, p["attn"], h, c, pos)
-                return x1 + a, write_cache(c, rows, pos)
-
-            def moe_ffn(p, x1):
-                h = norm(cfg, p["ln2"], x1)
-                y, _ = MOE.moe_block(cfg, p["moe"], h)
-                return x1 + y
-
-            segs["attn"] = jax.jit(moe_attn)
-            segs["moe"] = jax.jit(moe_ffn)
-        elif fam == "ssm":
-            segs["time_mix"] = jax.jit(
-                functools.partial(R.rwkv_time_mix_step, cfg))
-            segs["channel_mix"] = jax.jit(
-                functools.partial(R.rwkv_channel_mix_step, cfg))
-        else:  # hybrid
-            def mamba_scan(lps, x1, lcs):
-                def body(xx, lpc):
-                    lp, lc = lpc
-                    delta, lc_new = M.mamba_decode_step(cfg, lp, xx, lc)
-                    return xx + delta, lc_new
-                return jax.lax.scan(body, x1, (lps, lcs))
-
-            segs["scan"] = jax.jit(mamba_scan)
-            segs["attn"] = jax.jit(dense_attn)
-            segs["mlp"] = jax.jit(dense_mlp)
-        return segs
-
-    # -- params slicing (once per params object) -----------------------------
-
-    def _sliced(self, params):
-        if self._params_id != id(params):
-            gps = [jax.tree.map(lambda a: a[g], params["blocks"])
-                   for g in range(self.cfg.num_groups)]
-            head_w = params["embed"] if self.cfg.tie_embeddings \
-                else params["lm_head"]
-            aux = {"embed": params["embed"], "head_w": head_w,
-                   "final_norm": params["final_norm"]}
-            if self.cfg.family == "hybrid":
-                aux["shared"] = params["shared_block"]
-            jax.block_until_ready(gps)
-            self._gps, self._aux, self._params_id = gps, aux, id(params)
-        return self._gps, self._aux
-
-    # -- one profiled step ---------------------------------------------------
-
-    def __call__(self, params, cache_groups, tokens, pos
-                 ) -> Tuple[jax.Array, list, list]:
-        """Returns ``(logits, new_cache_groups, walls)`` where ``walls``
-        aligns with :func:`profile_ops` — one post-sync wall-clock
-        microsecond figure per segment."""
-        import time as _time
-        cfg = self.cfg
-        gps, aux = self._sliced(params)
-        segs = self._segs
-        walls: list = []
-
-        def timed(fn, *args):
-            t0 = _time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            walls.append((_time.perf_counter() - t0) * 1e6)
-            return out
-
-        x1 = timed(segs["embed"], aux["embed"], tokens)
-        new_groups = []
-        for g in range(cfg.num_groups):
-            gp, gc = gps[g], cache_groups[g]
-            if cfg.family == "dense" and cfg.local_global:
-                (x1, cl) = timed(segs["attn_local"], gp["local"], x1,
-                                 gc["local"], pos)
-                x1 = timed(segs["mlp_local"], gp["local"], x1)
-                (x1, cgl) = timed(segs["attn_global"], gp["global"], x1,
-                                  gc["global"], pos)
-                x1 = timed(segs["mlp_global"], gp["global"], x1)
-                new_groups.append({"local": cl, "global": cgl})
-            elif cfg.family in ("dense", "moe"):
-                (x1, c_new) = timed(segs["attn"], gp["lyr"], x1,
-                                    gc["lyr"], pos)
-                x1 = timed(segs["mlp" if cfg.family == "dense" else "moe"],
-                           gp["lyr"], x1)
-                new_groups.append({"lyr": c_new})
-            elif cfg.family == "ssm":
-                (x1, c_tm) = timed(segs["time_mix"], gp["lyr"], x1,
-                                   gc["lyr"])
-                (x1, c_cm) = timed(segs["channel_mix"], gp["lyr"], x1,
-                                   gc["lyr"])
-                new_groups.append({"lyr": {**c_tm, **c_cm}})
-            else:  # hybrid
-                (x1, mamba_new) = timed(segs["scan"], gp["mamba"], x1,
-                                        gc["mamba"])
-                (x1, attn_new) = timed(segs["attn"], aux["shared"], x1,
-                                       gc["attn"], pos)
-                x1 = timed(segs["mlp"], aux["shared"], x1)
-                new_groups.append({"mamba": mamba_new, "attn": attn_new})
-        logits = timed(segs["head"], aux["final_norm"], aux["head_w"], x1)
-        return logits, new_groups, walls
-
-
-@functools.lru_cache(maxsize=None)
-def make_profiled_serve_step(cfg: ModelConfig) -> ProfiledServeStep:
-    """Per-config cached :class:`ProfiledServeStep` (same sharing contract
-    as :func:`make_serve_step` — every profiled engine/driver on one config
-    shares one set of compiled segments)."""
-    return ProfiledServeStep(cfg)

@@ -190,13 +190,34 @@ def _serve(cfg, params, slots, requests, max_len=24, metrics=None):
 REQUESTS = [([3, 9, 4], 8), ([5, 1], 9)]
 
 
-def test_free_slots_beside_a_request_leave_its_tokens_alone():
+def _gqa_qwen2(**kw):
+    """The reduced qwen2-0.5b at its published head shape (14/2 x 64)."""
+    return dataclasses.replace(get_config("qwen2-0.5b").reduced(),
+                               num_heads=14, num_kv_heads=2, head_dim=64, **kw)
+
+
+def _reduced(arch, **kw):
+    return lambda: dataclasses.replace(get_config(arch).reduced(), **kw)
+
+
+# every family the engine serves, and the int8 K/V cache
+SERVED = {
+    "qwen2-0.5b": _gqa_qwen2,
+    **{arch: _reduced(arch) for arch in (
+        "gemma2-27b", "starcoder2-7b", "olmoe-1b-7b", "rwkv6-7b",
+        "zamba2-7b", "whisper-large-v3", "llama-3.2-vision-11b")},
+    "qwen2-0.5b-int8": lambda: _gqa_qwen2(kv_cache_dtype="int8"),
+    "gemma2-27b-int8": _reduced("gemma2-27b", kv_cache_dtype="int8"),
+}
+
+
+@pytest.mark.parametrize("name", list(SERVED))
+def test_free_slots_beside_a_request_leave_its_tokens_alone(name):
     """The same two requests in an engine with no free slot, and in one
     with a slot never taken and a short request that frees its slot after
-    two steps: the kernel reads nothing of a free slot (length 0), and the
-    two requests get the same tokens."""
-    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
-                              num_heads=14, num_kv_heads=2, head_dim=64)
+    two steps: attention reads nothing of a free slot (length 0), and the
+    two requests get the same tokens, whatever shares their batch."""
+    cfg = SERVED[name]()
     params = MP.init_params(cfg, seed=2)
     _, alone = _serve(cfg, params, 2, REQUESTS)
     _, beside = _serve(cfg, params, 4, REQUESTS + [([7], 1)])
@@ -208,8 +229,7 @@ def test_live_counter_sums_each_slots_length():
     """With a registry, each step adds the slots' lengths (the shared
     position for an occupied slot, nothing for a free one), whichever path
     attention takes; the gauge holds slots x max_len."""
-    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
-                              num_heads=14, num_kv_heads=2, head_dim=64)
+    cfg = _gqa_qwen2()
     params = MP.init_params(cfg, seed=2)
     max_len = 80
     occupied = []

@@ -14,6 +14,7 @@ from repro.models import transformer as TF
 # one representative per family mechanic
 ARCHS = ["qwen2-0.5b",          # dense GQA + bias + rope
          "gemma2-27b",          # local/global + ring buffer + softcaps
+         "starcoder2-7b",       # dense GQA + ungated GELU MLP
          "olmoe-1b-7b",         # MoE
          "rwkv6-7b",            # attention-free state
          "zamba2-7b",           # mamba states + shared attn

@@ -109,11 +109,22 @@ def test_backoff_deterministic_and_capped():
 # -- cache slot surgery ------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "rwkv6-7b"])
-def test_cache_slot_reset_and_corrupt(arch):
+@pytest.mark.parametrize("arch,kv", [
+    pytest.param(arch, None, id=arch) for arch in (
+        "qwen2-0.5b", "rwkv6-7b", "gemma2-27b", "starcoder2-7b",
+        "olmoe-1b-7b", "zamba2-7b", "whisper-large-v3",
+        "llama-3.2-vision-11b")] + [
+    pytest.param("qwen2-0.5b", "int8", id="qwen2-0.5b-int8")])
+def test_cache_slot_reset_and_corrupt(arch, kv):
+    """Every cache layout: the batch axis of each leaf is found, and one
+    slot is poisoned and zeroed there without touching its neighbours."""
+    import dataclasses
+
     import jax
 
     cfg = get_config(arch).reduced()
+    if kv is not None:
+        cfg = dataclasses.replace(cfg, kv_cache_dtype=kv)
     params = MP.init_params(cfg, seed=SEED)
     cache = decode.init_cache(cfg, params, 3, 8)
     # write recognizable values everywhere, then poison slot 1 only

@@ -59,7 +59,7 @@ def executed_instructions(hlo):
     return out
 
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "olmoe-1b-7b"])
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "olmoe-1b-7b", "starcoder2-7b"])
 def test_every_operation_of_the_step_carries_a_scope(arch):
     cfg = get_config(arch).reduced()
     compiled = decode.make_serve_step(cfg).lower(
